@@ -42,7 +42,7 @@ def run(verbose: bool = True) -> List[Dict]:
     # --- Fig 6: prefill time vs total input length (batch-size invariant) ---
     # sizes share one attention code path (dense: all % kv_chunk != 0)
     xs, ts = [], []
-    f = jax.jit(eng._prefill_fn)
+    f = eng._prefill_jit
     for s in (192, 320, 448, 576):
         toks = np.random.default_rng(0).integers(2, arch.vocab, (1, s))
         import jax.numpy as jnp

@@ -3,7 +3,7 @@
 The three simulation engines (reference / vectorized / jax) agree only
 because a set of invariants holds that ordinary linters cannot see: the
 ``fastsim_jax`` performance contract (never bulk-scatter into trace-sized
-carries inside the beat loop), the scoped-``enable_x64()`` precision
+carries inside the beat loop), the scoped-``jax.enable_x64(True)`` precision
 discipline, dimensional consistency of the second/token/GPU-second
 arithmetic, monotone causal clocks stamped only by blessed helpers, frozen
 deprecation shims, and envelope validators that must inspect every scenario

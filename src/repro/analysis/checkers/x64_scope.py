@@ -2,11 +2,11 @@
 
 PR 6 settled the precision discipline: the compiled cores run in
 float32/int32 by default and opt into doubles only under a scoped
-``with enable_x64():`` block, so one import can never flip dtype
+``with jax.enable_x64(True):`` block, so one import can never flip dtype
 semantics for the rest of the process (and with it, the bit-for-bit
 equivalence grid).  This checker flags the three escape hatches:
 ``jax.config.update("jax_enable_x64", ...)``, assignment to
-``config.jax_enable_x64``, and a bare ``enable_x64()`` call used as a
+``config.jax_enable_x64``, and a bare ``enable_x64(...)`` call used as a
 statement instead of a ``with`` context.
 """
 from __future__ import annotations
@@ -33,16 +33,17 @@ class X64Scope(Checker):
                     diags.append(src.diag(
                         "SIM002", node,
                         "process-global `config.update(\"jax_enable_x64\""
-                        ", ...)`; use a scoped `with enable_x64():` block"))
+                        ", ...)`; use a scoped "
+                        "`with jax.enable_x64(True):` block"))
                 elif fname.rsplit(".", 1)[-1] == "enable_x64":
                     parent = getattr(node, "parent", None)
                     in_with = isinstance(parent, ast.withitem)
                     if not in_with:
                         diags.append(src.diag(
                             "SIM002", node,
-                            "`enable_x64()` outside a `with` statement "
+                            "`enable_x64(...)` outside a `with` statement "
                             "leaks 64-bit mode; use "
-                            "`with enable_x64():`"))
+                            "`with jax.enable_x64(True):`"))
             elif isinstance(node, (ast.Assign, ast.AugAssign)):
                 targets = (node.targets if isinstance(node, ast.Assign)
                            else [node.target])
@@ -52,5 +53,6 @@ class X64Scope(Checker):
                         diags.append(src.diag(
                             "SIM002", node,
                             "direct assignment to `config.jax_enable_x64`"
-                            "; use a scoped `with enable_x64():` block"))
+                            "; use a scoped "
+                            "`with jax.enable_x64(True):` block"))
         return diags

@@ -29,7 +29,8 @@ CODES: Dict[str, str] = {
                "Python branching, or tracer coercions inside compiled "
                "beat-loop bodies and Pallas kernels"),
     "SIM002": ("x64 scope: jax 64-bit precision may only be enabled via a "
-               "scoped `with enable_x64():` block, never process-globally"),
+               "scoped `with jax.enable_x64(True):` block, never "
+               "process-globally"),
     "SIM003": ("unit safety: additions/comparisons must not mix dimensions "
                "(seconds vs tokens vs GPU-seconds vs price) inferred from "
                "the repo's naming conventions"),

@@ -1,7 +1,10 @@
 """Pallas TPU paged decode-attention kernel (flash-decoding over a page pool).
 
-The KV cache lives in HBM as a global page pool ``(n_pages, page, Hkv, D)``;
-each sequence owns a list of pages (block table). The kernel walks a
+The KV cache lives in HBM as a global head-major page pool
+``(n_pages, Hkv, page, D)``: one KV head's (page, D) tile is a block whose
+last two dims equal the array's own, which the TPU tiling rule requires
+(keep ``page`` a multiple of 8 for f32 pools, 16 for bf16). Each sequence
+owns a list of pages (block table). The kernel walks a
 sequence's pages (scalar-prefetched block table drives the BlockSpec index
 map, i.e. page indirection happens at DMA-issue time, the TPU analogue of
 vLLM's gather inside the CUDA kernel), computing a running flash-softmax
@@ -44,8 +47,8 @@ def _decode_kernel(block_table_ref, lengths_ref,      # scalar-prefetch
     @pl.when(page_start < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # (group, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # (page, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -72,10 +75,10 @@ def _decode_kernel(block_table_ref, lengths_ref,      # scalar-prefetch
 def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, lengths,
                                   *, scale: Optional[float] = None,
                                   interpret: bool = False) -> jnp.ndarray:
-    """q: (B, Hq, D); k/v_pages: (n_pages, page, Hkv, D);
+    """q: (B, Hq, D); k/v_pages: (n_pages, Hkv, page, D);
     block_table: (B, max_pages) int32; lengths: (B,) int32 -> (B, Hq, D)."""
     b, hq, d = q.shape
-    n_pages, page_size, hkv, _ = k_pages.shape
+    n_pages, hkv, page_size, _ = k_pages.shape
     assert hq % hkv == 0
     group = hq // hkv
     max_pages = block_table.shape[1]
@@ -83,7 +86,7 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, lengths,
 
     # (B, Hkv, group, D) so a (group, D) q tile maps to one kv head.
     qg = q.reshape(b, hkv, group, d)
-    # Pages laid out (page, Hkv, D); block index map picks (page_id, head).
+    # Pages laid out (Hkv, page, D); block index map picks (page_id, head).
     kernel = functools.partial(_decode_kernel, scale=scale,
                                page_size=page_size, max_pages=max_pages,
                                group=group)
@@ -93,10 +96,10 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, lengths,
         in_specs=[
             pl.BlockSpec((1, 1, group, d),
                          lambda bi, h, pi, bt, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, d),
-                         lambda bi, h, pi, bt, ln: (bt[bi, pi], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, d),
-                         lambda bi, h, pi, bt, ln: (bt[bi, pi], 0, h, 0)),
+            pl.BlockSpec((1, 1, page_size, d),
+                         lambda bi, h, pi, bt, ln: (bt[bi, pi], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, d),
+                         lambda bi, h, pi, bt, ln: (bt[bi, pi], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, d),
                                lambda bi, h, pi, bt, ln: (bi, h, 0, 0)),
@@ -111,5 +114,6 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_table, lengths, qg, k_pages, v_pages)
     return out.reshape(b, hq, d)

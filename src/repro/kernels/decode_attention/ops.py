@@ -14,10 +14,7 @@ from repro.kernels.decode_attention.ref import (    # noqa: F401 (re-export)
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:                                  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, lengths, *,

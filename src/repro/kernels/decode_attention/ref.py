@@ -88,14 +88,16 @@ def paged_decode_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     """Paged decode attention.
 
     q:           (B, Hq, D)
-    k/v_pages:   (n_pages, page_size, Hkv, D)  — global page pool
+    k/v_pages:   (n_pages, Hkv, page_size, D)  — head-major page pool
     block_table: (B, max_pages) int32          — page ids per sequence
     lengths:     (B,) int32                    — valid tokens per sequence
     """
     b, hq, d = q.shape
-    _, page_size, hkv, _ = k_pages.shape
+    _, hkv, page_size, _ = k_pages.shape
     max_pages = block_table.shape[1]
-    # Gather this batch's pages into contiguous (B, S, Hkv, D).
-    k = k_pages[block_table].reshape(b, max_pages * page_size, hkv, d)
-    v = v_pages[block_table].reshape(b, max_pages * page_size, hkv, d)
-    return decode_attention_ref(q, k, v, lengths, scale)
+
+    def gather(pages):   # this batch's pages as contiguous (B, S, Hkv, D)
+        return pages[block_table].swapaxes(2, 3).reshape(
+            b, max_pages * page_size, hkv, d)
+    return decode_attention_ref(q, gather(k_pages), gather(v_pages),
+                                lengths, scale)

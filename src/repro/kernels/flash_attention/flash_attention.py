@@ -115,5 +115,6 @@ def flash_attention_pallas(q, k, v, *, scale: Optional[float] = None,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qT, kT, vT)
     return out.swapaxes(1, 2)

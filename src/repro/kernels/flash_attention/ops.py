@@ -13,10 +13,7 @@ from repro.kernels.flash_attention.ref import (     # noqa: F401 (re-export)
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:                                  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, q_offset=0,

@@ -33,17 +33,20 @@ def _rmsnorm_res_kernel(x_ref, r_ref, w_ref, o_ref, *, eps: float):
 def rmsnorm_pallas(x, w, residual: Optional[jnp.ndarray] = None,
                    *, eps: float = 1e-5, block_rows: int = 128,
                    interpret: bool = False) -> jnp.ndarray:
-    """x: (..., D); w: (D,). Rows flattened and tiled."""
+    """x: (..., D); w: (D,). Rows flattened and tiled; a ragged last tile
+    is zero-padded (a 1-row tile would break the TPU's 8-row tiling)."""
     shape = x.shape
     d = shape[-1]
     rows = 1
     for s in shape[:-1]:
         rows *= s
-    x2 = x.reshape(rows, d)
     block_rows = min(block_rows, rows)
-    if rows % block_rows:
-        block_rows = 1
-    grid = (rows // block_rows,)
+    pad = -rows % block_rows
+
+    def tiles(a):
+        return jnp.pad(a.reshape(rows, d), ((0, pad), (0, 0)))
+    x2 = tiles(x)
+    grid = ((rows + pad) // block_rows,)
     w2 = w.reshape(1, d)
     if residual is None:
         out = pl.pallas_call(
@@ -52,8 +55,9 @@ def rmsnorm_pallas(x, w, residual: Optional[jnp.ndarray] = None,
             in_specs=[pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
                       pl.BlockSpec((1, d), lambda i: (0, 0))],
             out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+            out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
             interpret=interpret,
+            name="rmsnorm",
         )(x2, w2)
     else:
         out = pl.pallas_call(
@@ -63,7 +67,8 @@ def rmsnorm_pallas(x, w, residual: Optional[jnp.ndarray] = None,
                       pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
                       pl.BlockSpec((1, d), lambda i: (0, 0))],
             out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+            out_shape=jax.ShapeDtypeStruct((rows + pad, d), x.dtype),
             interpret=interpret,
-        )(x2, residual.reshape(rows, d), w2)
-    return out.reshape(shape)
+            name="rmsnorm_residual",
+        )(x2, tiles(residual), w2)
+    return out[:rows].reshape(shape)

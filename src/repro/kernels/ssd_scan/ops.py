@@ -11,10 +11,7 @@ from repro.kernels.ssd_scan.ssd_scan import ssd_scan_pallas
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:                                  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def ssd_scan(x, dt, A, Bm, Cm, D, init_state=None, *, chunk: int = 64,

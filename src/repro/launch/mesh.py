@@ -24,10 +24,3 @@ def make_production_mesh(*, multi_pod: bool = False):
     dev_array = np.asarray(devices[:n]).reshape(shape)
     return jax.sharding.Mesh(dev_array, axes)
 
-
-def make_worker_mesh(n_model: int = 1):
-    """Small TP mesh for one serving worker (e.g. 4 chips TP)."""
-    devices = jax.devices()[:n_model]
-    import numpy as np
-    return jax.sharding.Mesh(np.asarray(devices).reshape(1, n_model),
-                             ("data", "model"))

@@ -83,7 +83,7 @@ def _apply_rope(arch, q, k, positions):
 def self_attention_full(x, p, arch, policy: Policy = NO_POLICY, *,
                         positions: Optional[jnp.ndarray] = None,
                         kv_chunk: int = 256, use_pallas: bool = False,
-                        return_kv: bool = False):
+                        interpret: bool = False, return_kv: bool = False):
     """Causal full-sequence self-attention (train / prefill)."""
     b, s, d = x.shape
     q, k, v = _qkv(x, p, arch, policy)
@@ -91,7 +91,7 @@ def self_attention_full(x, p, arch, policy: Policy = NO_POLICY, *,
         positions = jnp.arange(s)
     q, k = _apply_rope(arch, q, k, positions)
     out = flash_attention(q, k, v, causal=True, kv_chunk=kv_chunk,
-                          use_pallas=use_pallas)
+                          use_pallas=use_pallas, interpret=interpret)
     out = policy.constrain(out, ("batch", "seq_q", "heads", None))
     out = out.reshape(b, s, -1) @ p["wo"]
     if return_kv:

@@ -8,15 +8,14 @@ import jax
 import jax.numpy as jnp
 
 
-def rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
-    """RMSNorm; on TPU dispatches to the fused Pallas kernel
-    (kernels/rmsnorm), elsewhere the pure-jnp form below (identical math)."""
-    try:
-        if jax.default_backend() == "tpu":
-            from repro.kernels.rmsnorm import rmsnorm_pallas
-            return rmsnorm_pallas(x, w, eps=eps)
-    except Exception:       # pragma: no cover — fall through to jnp
-        pass
+def rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-5, *,
+             use_pallas: bool = False, interpret: bool = False) -> jnp.ndarray:
+    """RMSNorm; ``use_pallas`` runs the fused Pallas kernel (kernels/rmsnorm),
+    otherwise the pure-jnp form below (identical math). The caller's kernel
+    choice (``ExecConfig`` or the engine's) decides, never the backend."""
+    if use_pallas:
+        from repro.kernels.rmsnorm import rmsnorm_pallas
+        return rmsnorm_pallas(x, w, eps=eps, interpret=interpret)
     dtype = x.dtype
     x = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)

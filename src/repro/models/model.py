@@ -23,6 +23,7 @@ without materializing anything.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -44,6 +45,7 @@ from repro.models.moe import moe_ffn, padded_experts, shared_expert_ffn
 @dataclasses.dataclass(frozen=True)
 class ExecConfig:
     use_pallas: bool = False
+    interpret: bool = False        # Pallas interpret mode (CPU tests only)
     kv_chunk: int = 256
     scan_layers: bool = True
     remat: bool = False
@@ -158,6 +160,18 @@ def _cross_layer_leaves(arch: ArchConfig) -> Dict[str, Leaf]:
     out.update(_attn_leaves(arch))
     out.update(_mlp_leaves(arch, arch.d_ff))
     return out
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _normal_leaf(key, scale, shape, dtype):
+    """One scaled-normal weight, generated straight into ``dtype``: fused
+    under jit, so a (32, 4096, 11008) bf16 leaf never exists in f32.
+    The no-op ``reduce_precision`` stops XLA from reassociating ``* scale``
+    with the sampler's own ``* sqrt(2)``, which would round differently
+    from the eager product and change the weights of a fixed seed."""
+    n = jax.lax.reduce_precision(jax.random.normal(key, shape, jnp.float32),
+                                 exponent_bits=8, mantissa_bits=23)
+    return (n * scale).astype(dtype)
 
 
 def _stack(leaves: Dict[str, Leaf], *ns: int) -> Dict[str, Leaf]:
@@ -275,24 +289,30 @@ class LM:
             elif scale == 0.0:
                 out.append(jnp.zeros(shape, self.dtype))
             else:
-                out.append((jax.random.normal(k, shape, jnp.float32)
-                            * scale).astype(self.dtype))
+                out.append(_normal_leaf(k, jnp.float32(scale), shape,
+                                        self.dtype))
         return jax.tree.unflatten(treedef, out)
 
     # =========================================================================
     # layer bodies
     # =========================================================================
+    def _norm(self, x, w):
+        c = self.cfg
+        return rms_norm(x, w, self.arch.norm_eps, use_pallas=c.use_pallas,
+                        interpret=c.interpret)
+
     def _dense_layer_full(self, x, p, positions, return_cache):
         a, pol = self.arch, self.policy
-        h = rms_norm(x, p["ln1"], a.norm_eps)
+        h = self._norm(x, p["ln1"])
         res = self_attention_full(h, p, a, pol, positions=positions,
                                   kv_chunk=self.cfg.kv_chunk,
                                   use_pallas=self.cfg.use_pallas,
+                                  interpret=self.cfg.interpret,
                                   return_kv=return_cache)
         if return_cache:
             res, kv = res
         x = x + res
-        h = rms_norm(x, p["ln2"], a.norm_eps)
+        h = self._norm(x, p["ln2"])
         h = gated_mlp(h, p["wg"], p["wu"], p["wd"], a.act)
         h = pol.constrain(h, ("batch", "seq_q", None))
         x = x + h
@@ -300,15 +320,16 @@ class LM:
 
     def _moe_layer_full(self, x, p, positions, return_cache):
         a, pol = self.arch, self.policy
-        h = rms_norm(x, p["ln1"], a.norm_eps)
+        h = self._norm(x, p["ln1"])
         res = self_attention_full(h, p, a, pol, positions=positions,
                                   kv_chunk=self.cfg.kv_chunk,
                                   use_pallas=self.cfg.use_pallas,
+                                  interpret=self.cfg.interpret,
                                   return_kv=return_cache)
         if return_cache:
             res, kv = res
         x = x + res
-        h = rms_norm(x, p["ln2"], a.norm_eps)
+        h = self._norm(x, p["ln2"])
         out, aux = moe_ffn(h, p, a, pol, self.cfg.capacity_factor)
         if a.moe.n_shared_experts:
             out = out + shared_expert_ffn(h, p, a, pol)
@@ -317,10 +338,10 @@ class LM:
 
     def _moe_layer_decode(self, x, p, cache: AttnCache):
         a, pol = self.arch, self.policy
-        h = rms_norm(x, p["ln1"], a.norm_eps)
+        h = self._norm(x, p["ln1"])
         res, cache = self_attention_decode(h, cache, p, a, pol)
         x = x + res
-        h = rms_norm(x, p["ln2"], a.norm_eps)
+        h = self._norm(x, p["ln2"])
         out, _ = moe_ffn(h[:, None, :], p, a, pol, self.cfg.capacity_factor)
         out = out[:, 0]
         if a.moe.n_shared_experts:
@@ -329,39 +350,39 @@ class LM:
 
     def _dense_layer_decode(self, x, p, cache: AttnCache):
         a, pol = self.arch, self.policy
-        h = rms_norm(x, p["ln1"], a.norm_eps)
+        h = self._norm(x, p["ln1"])
         res, cache = self_attention_decode(h, cache, p, a, pol)
         x = x + res
-        h = rms_norm(x, p["ln2"], a.norm_eps)
+        h = self._norm(x, p["ln2"])
         x = x + gated_mlp(h, p["wg"], p["wu"], p["wd"], a.act)
         return x, cache
 
     def _cross_layer_full(self, x, p, frontend, return_cache):
         a, pol = self.arch, self.policy
-        h = rms_norm(x, p["ln1"], a.norm_eps)
+        h = self._norm(x, p["ln1"])
         res = cross_attention_full(h, frontend, p, a, pol,
                                    use_pallas=self.cfg.use_pallas,
                                    return_kv=return_cache)
         if return_cache:
             res, kv = res
         x = x + jnp.tanh(p["gate_attn"].astype(jnp.float32)).astype(x.dtype) * res
-        h = rms_norm(x, p["ln2"], a.norm_eps)
+        h = self._norm(x, p["ln2"])
         h = gated_mlp(h, p["wg"], p["wu"], p["wd"], a.act)
         x = x + jnp.tanh(p["gate_mlp"].astype(jnp.float32)).astype(x.dtype) * h
         return (x, kv) if return_cache else (x, None)
 
     def _cross_layer_decode(self, x, p, cross_kv):
         a = self.arch
-        h = rms_norm(x, p["ln1"], a.norm_eps)
+        h = self._norm(x, p["ln1"])
         res = cross_attention_decode(h, cross_kv, p, a, self.policy)
         x = x + jnp.tanh(p["gate_attn"].astype(jnp.float32)).astype(x.dtype) * res
-        h = rms_norm(x, p["ln2"], a.norm_eps)
+        h = self._norm(x, p["ln2"])
         h = gated_mlp(h, p["wg"], p["wu"], p["wd"], a.act)
         return x + jnp.tanh(p["gate_mlp"].astype(jnp.float32)).astype(x.dtype) * h
 
     def _mamba_layer_full(self, x, p, return_cache):
         a = self.arch
-        h = rms_norm(x, p["ln"], a.norm_eps)
+        h = self._norm(x, p["ln"])
         res = mamba_block_full(h, p, a, self.policy,
                                use_pallas=self.cfg.use_pallas,
                                return_cache=return_cache)
@@ -372,7 +393,7 @@ class LM:
 
     def _mamba_layer_decode(self, x, p, cache: MambaCache):
         a = self.arch
-        h = rms_norm(x, p["ln"], a.norm_eps)
+        h = self._norm(x, p["ln"])
         res, cache = mamba_block_decode(h, cache, p, a, self.policy)
         return x + res, cache
 
@@ -476,7 +497,7 @@ class LM:
                 caches.append(ys)
             else:
                 raise ValueError(seg.kind)
-        x = rms_norm(x, params["final_ln"], self.arch.norm_eps)
+        x = self._norm(x, params["final_ln"])
         return x, caches, aux_sum
 
     # -- losses ----------------------------------------------------------------
@@ -775,7 +796,7 @@ class LM:
                     "dense": {**cd, "k_rec": recs[0], "v_rec": recs[1],
                               "rec_len": cd["rec_len"] + 1},
                     "cross_kv": c["cross_kv"]})
-        x = rms_norm(x, params["final_ln"], a.norm_eps)
+        x = self._norm(x, params["final_ln"])
         logits = (x.astype(jnp.float32)
                   @ self._head_weight(params).astype(jnp.float32))
         logits = self.policy.constrain(logits, ("batch", "vocab"))

@@ -21,7 +21,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import NO_POLICY, Policy
@@ -124,7 +123,7 @@ def moe_ffn(x: jnp.ndarray, p: dict, arch, policy: Policy = NO_POLICY,
             return out.reshape(xb.shape), aux
 
         batch_spec = policy.spec(("batch",))[0]
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             ranked, mesh=mesh,
             in_specs=(P(batch_spec, None, None), P(),
                       P("model", None, None), P("model", None, None),

@@ -1,7 +1,8 @@
 """Cluster manager: Aladdin's control plane over real engine workers.
 
-Runs the paper's full loop on live ``PagedEngine`` workers (tiny models on
-CPU; TPU slices in production):
+Runs the paper's full loop on live ``PagedEngine`` workers, each on its
+own device while devices last (one TPU chip each in production; on a
+one-device CPU host they all share it):
 
   submit -> predict l_out -> best-fit place (Alg. 1) -> engines run
   iteration-level batching -> traces refit the perf models -> re-balance
@@ -25,6 +26,7 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.perf_model import analytic_perf_model
@@ -101,9 +103,13 @@ class ServingCluster:
 
     # ---- worker lifecycle ----------------------------------------------------
     def _spawn_worker(self) -> ClusterWorker:
-        self._wid += 1
+        # one device per worker, round-robin: worker k (from 0) holds its
+        # weights and KV pool on jax.devices()[k % n]
+        devices = jax.devices()
         eng = PagedEngine(self.arch, self.params, self.engine_cfg,
-                          time_fn=self.time_fn)
+                          time_fn=self.time_fn,
+                          device=devices[self._wid % len(devices)])
+        self._wid += 1
         st = WorkerState(self._wid, self.pcfg, self.perf, self.slo)
         w = ClusterWorker(self._wid, eng, st)
         self.workers[self._wid] = w

@@ -4,8 +4,12 @@ Slot-based execution over a page pool: each running request owns a slot and a
 list of pages (block table). Iteration-level scheduling (Orca-style): new
 requests run a prefill iteration (preempting decode, as vLLM does — the
 paper's constraint (d) budgets exactly this), otherwise all running slots
-advance one decode step via paged attention. On TPU the paged Pallas kernel
-is the attention path; on CPU the jnp oracle.
+advance one decode step via paged attention. The engine picks its attention
+kernels once, from the device it runs on: on a TPU, prefill runs the compiled
+Pallas flash-attention kernel, decode the compiled Pallas paged kernel, and
+both the Pallas RMSNorm; elsewhere all run the jnp references.
+``EngineConfig.interpret`` runs the same Pallas kernels in interpret mode
+(CPU tests).
 
 Supports dense/GQA transformer archs (the paper's Llama-2 family). Execution
 is real JAX compute — iteration wall-times feed the TraceBuffer that fits the
@@ -13,9 +17,10 @@ paper's performance models (Eqs. 1-3)."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
-from typing import Callable, List, Optional
+from typing import Callable, Iterable, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,44 +37,150 @@ from repro.models.model import LM, ExecConfig
 @dataclasses.dataclass
 class EngineConfig:
     max_batch: int = 8
-    page_size: int = 16
+    page_size: int = 16             # multiple of 8 (the f32 pool's TPU tile)
     n_pages: int = 512
     max_pages_per_seq: int = 64
     max_new_tokens: int = 2048
-    use_pallas: bool = False        # pallas paged kernel (interpret on CPU)
+    interpret: bool = False         # Pallas kernels in interpret mode (CPU
+                                    # tests only); a TPU always compiles them
     prefill_chunk: int = 0          # >0: Sarathi-style chunked prefill — at
                                     # most this many prompt tokens per
                                     # iteration, bounding decode preemption
                                     # stalls (shrinks constraint (d) pressure)
 
 
+def prompt_bucket(n_tokens: int) -> int:
+    """Power-of-two prefill length bucket (one compiled program each)."""
+    return max(8, 1 << (n_tokens - 1).bit_length())
+
+
+# ---- jitted model math (module-level: engines on one device share the
+# compiled programs) ----------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("arch", "use_pallas",
+                                             "interpret"))
+def prefill_step(params, tokens, logit_pos, *, arch: ArchConfig,
+                 use_pallas: bool, interpret: bool):
+    """tokens: (1, S_bucket) -> (logits (V,), ks, vs (L, S, Hkv, hd)).
+    S is a power-of-two bucket; real length = logit_pos + 1 (causal
+    attention makes the tail padding inert)."""
+    model = LM(arch, exec_cfg=ExecConfig(scan_layers=True,
+                                         use_pallas=use_pallas,
+                                         interpret=interpret))
+    logits, cache = model.prefill(params, tokens=tokens,
+                                  s_max=tokens.shape[1], logit_pos=logit_pos)
+    c0 = cache[0]
+    return (logits[0], c0["k_big"][:, 0].astype(jnp.float32),
+            c0["v_big"][:, 0].astype(jnp.float32))
+
+
+@functools.partial(jax.jit, static_argnames=("arch", "page_size",
+                                             "use_pallas", "interpret"))
+def decode_step(params, kv_k, kv_v, block_tables, lengths, tokens, active, *,
+                arch: ArchConfig, page_size: int, use_pallas: bool,
+                interpret: bool):
+    """One decode iteration for every slot (inactive ones masked).
+    kv_k/kv_v: (L, n_pages, Hkv, page, hd). Returns (logits, new kv_k,
+    new kv_v)."""
+    a = arch
+    hd = a.resolved_head_dim
+    x = params["embed"][tokens].astype(jnp.float32)
+    if a.tie_embeddings:
+        x = x * math.sqrt(a.d_model)
+    if a.pos_emb == PosEmb.SINUSOIDAL:
+        x = x + sinusoidal_pos(lengths, a.d_model).astype(x.dtype)
+    page_ids = jnp.take_along_axis(
+        block_tables, (lengths // page_size)[:, None], axis=1)[:, 0]
+    offs = lengths % page_size
+    msk = active[:, None, None]
+    norm = functools.partial(rms_norm, eps=a.norm_eps, use_pallas=use_pallas,
+                             interpret=interpret)
+
+    def layer(x, inp):
+        p, kk, vv = inp   # one layer's weights, (n_pages, Hkv, page, hd) pools
+        h = norm(x, p["ln1"])
+        q = (h @ p["wq"]).reshape(-1, a.n_heads, hd)
+        k = (h @ p["wk"]).reshape(-1, a.n_kv_heads, hd)
+        v = (h @ p["wv"]).reshape(-1, a.n_kv_heads, hd)
+        if a.qkv_bias:
+            q = q + p["bq"].reshape(a.n_heads, hd)
+            k = k + p["bk"].reshape(a.n_kv_heads, hd)
+            v = v + p["bv"].reshape(a.n_kv_heads, hd)
+        if a.pos_emb == PosEmb.ROPE:
+            q = rope(q[:, None], lengths[:, None], a.rope_theta)[:, 0]
+            k = rope(k[:, None], lengths[:, None], a.rope_theta)[:, 0]
+        # (page_ids, :, offs) selects (B, Hkv, hd): one token slot per seq
+        kk = kk.at[page_ids, :, offs].set(
+            jnp.where(msk, k, kk[page_ids, :, offs]))
+        vv = vv.at[page_ids, :, offs].set(
+            jnp.where(msk, v, vv[page_ids, :, offs]))
+        att = paged_decode_attention(q, kk, vv, block_tables, lengths + 1,
+                                     use_pallas=use_pallas,
+                                     interpret=interpret)
+        x = x + att.reshape(x.shape[0], -1) @ p["wo"]
+        h = norm(x, p["ln2"])
+        x = x + gated_mlp(h, p["wg"], p["wu"], p["wd"], a.act)
+        return x, (kk, vv)
+
+    x, (kv_k, kv_v) = jax.lax.scan(layer, x, (params["seg0"], kv_k, kv_v))
+    x = norm(x, params["final_ln"])
+    head = params["embed"].T if a.tie_embeddings else params["head"]
+    return x @ head.astype(x.dtype), kv_k, kv_v
+
+
 class PagedEngine:
     """One worker's execution engine."""
 
     def __init__(self, arch: ArchConfig, params, cfg: EngineConfig,
-                 time_fn: Callable[[], float] = time.perf_counter):
+                 time_fn: Callable[[], float] = time.perf_counter,
+                 device: Optional[jax.Device] = None):
         assert arch.family in (Family.DENSE, Family.AUDIO), \
             "engine path supports dense GQA archs (the paper's models)"
         self.arch = arch
-        self.params = params
         self.cfg = cfg
         self.time_fn = time_fn
         self.traces = TraceBuffer()
+        self.device = device if device is not None else jax.devices()[0]
+        self.use_pallas = self.device.platform == "tpu" or cfg.interpret
+        self.params = jax.device_put(params, self.device)
         L = arch.n_layers
         hd = arch.resolved_head_dim
-        self.kv_k = jnp.zeros((L, cfg.n_pages, cfg.page_size,
-                               arch.n_kv_heads, hd), jnp.float32)
-        self.kv_v = jnp.zeros_like(self.kv_k)
+        pool = (L, cfg.n_pages, arch.n_kv_heads, cfg.page_size, hd)
+        self.kv_k = jnp.zeros(pool, jnp.float32, device=self.device)
+        self.kv_v = jnp.zeros(pool, jnp.float32, device=self.device)
         self.block_tables = np.zeros((cfg.max_batch, cfg.max_pages_per_seq),
                                      np.int32)
         self.lengths = np.zeros((cfg.max_batch,), np.int32)
         self.free_pages = list(range(cfg.n_pages - 1, 0, -1))  # page 0 = null
         self.slots: List[Optional[Request]] = [None] * cfg.max_batch
         self.waiting: List[Request] = []
-        self._prefill_jit = jax.jit(self._prefill_fn)
-        self._decode_jit = jax.jit(self._decode_fn)
+        kernels = dict(arch=arch, use_pallas=self.use_pallas,
+                       interpret=cfg.interpret)
+        self._prefill_jit = functools.partial(prefill_step, **kernels)
+        self._decode_jit = functools.partial(
+            decode_step, page_size=cfg.page_size, **kernels)
         self._chunk_jit = jax.jit(self._chunk_fn)
         self.kv_bytes_per_token = 2 * L * arch.n_kv_heads * hd * 4
+        # optional observer of every logits row the engine samples from:
+        # called as on_logits(request, logits (V,)) after prefill and after
+        # each decode step
+        self.on_logits: Optional[Callable[[Request, jax.Array], None]] = None
+
+    def warmup(self, prompt_lens: Iterable[int]) -> float:
+        """Compile, by running once, the prefill program of each prompt
+        length's bucket and the decode step, so serving never waits on the
+        compiler. Touches no request, page or trace. Returns seconds."""
+        t0 = time.perf_counter()
+        for s in sorted({prompt_bucket(n) for n in prompt_lens}):
+            out = self._prefill_jit(self.params, jnp.zeros((1, s), jnp.int32),
+                                    s - 1)
+            jax.block_until_ready(out)
+        b = self.cfg.max_batch
+        logits, self.kv_k, self.kv_v = self._decode_jit(   # all slots masked
+            self.params, self.kv_k, self.kv_v,
+            jnp.asarray(self.block_tables), jnp.asarray(self.lengths),
+            jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool))
+        jax.block_until_ready(logits)
+        return time.perf_counter() - t0
 
     # ---- admission / state --------------------------------------------------
     def can_admit(self, n_tokens_total: int) -> bool:
@@ -86,62 +197,6 @@ class PagedEngine:
 
     def kv_used_bytes(self) -> float:
         return float(self.lengths.sum()) * self.kv_bytes_per_token / 2
-
-    # ---- jitted model math --------------------------------------------------
-    def _prefill_fn(self, params, tokens, logit_pos):
-        """tokens: (1, S_bucket) -> (logits (V,), ks, vs (L, S, Hkv, hd)).
-        S is a power-of-two bucket; real length = logit_pos + 1 (causal
-        attention makes the tail padding inert)."""
-        model = LM(self.arch, exec_cfg=ExecConfig(scan_layers=True))
-        logits, cache = model.prefill(params, tokens=tokens,
-                                      s_max=tokens.shape[1],
-                                      logit_pos=logit_pos)
-        c0 = cache[0]
-        return (logits[0], c0["k_big"][:, 0].astype(jnp.float32),
-                c0["v_big"][:, 0].astype(jnp.float32))
-
-    def _decode_fn(self, params, kv_k, kv_v, block_tables, lengths, tokens,
-                   active):
-        """One decode iteration for every slot (inactive ones masked).
-        Returns (logits, new kv_k, new kv_v)."""
-        a = self.arch
-        hd = a.resolved_head_dim
-        x = params["embed"][tokens].astype(jnp.float32)
-        if a.tie_embeddings:
-            x = x * math.sqrt(a.d_model)
-        if a.pos_emb == PosEmb.SINUSOIDAL:
-            x = x + sinusoidal_pos(lengths, a.d_model).astype(x.dtype)
-        page_ids = jnp.take_along_axis(
-            block_tables, (lengths // self.cfg.page_size)[:, None],
-            axis=1)[:, 0]
-        offs = lengths % self.cfg.page_size
-        msk = active[:, None, None]
-        for i in range(a.n_layers):
-            p = jax.tree.map(lambda t: t[i], params["seg0"])
-            h = rms_norm(x, p["ln1"], a.norm_eps)
-            q = (h @ p["wq"]).reshape(-1, a.n_heads, hd)
-            k = (h @ p["wk"]).reshape(-1, a.n_kv_heads, hd)
-            v = (h @ p["wv"]).reshape(-1, a.n_kv_heads, hd)
-            if a.qkv_bias:
-                q = q + p["bq"].reshape(a.n_heads, hd)
-                k = k + p["bk"].reshape(a.n_kv_heads, hd)
-                v = v + p["bv"].reshape(a.n_kv_heads, hd)
-            if a.pos_emb == PosEmb.ROPE:
-                q = rope(q[:, None], lengths[:, None], a.rope_theta)[:, 0]
-                k = rope(k[:, None], lengths[:, None], a.rope_theta)[:, 0]
-            kv_k = kv_k.at[i, page_ids, offs].set(
-                jnp.where(msk, k, kv_k[i, page_ids, offs]))
-            kv_v = kv_v.at[i, page_ids, offs].set(
-                jnp.where(msk, v, kv_v[i, page_ids, offs]))
-            att = paged_decode_attention(
-                q, kv_k[i], kv_v[i], block_tables, lengths + 1,
-                use_pallas=self.cfg.use_pallas, interpret=self.cfg.use_pallas)
-            x = x + att.reshape(x.shape[0], -1) @ p["wo"]
-            h = rms_norm(x, p["ln2"], a.norm_eps)
-            x = x + gated_mlp(h, p["wg"], p["wu"], p["wd"], a.act)
-        x = rms_norm(x, params["final_ln"], a.norm_eps)
-        head = params["embed"].T if a.tie_embeddings else params["head"]
-        return x @ head.astype(x.dtype), kv_k, kv_v
 
     # ---- page management ----------------------------------------------------
     def _alloc_slot(self, req: Request, n_tokens: int) -> int:
@@ -218,6 +273,9 @@ class PagedEngine:
             jnp.asarray(tokens), jnp.asarray(active))
         nxt = np.asarray(jnp.argmax(logits, -1))
         t1 = self.time_fn()
+        if self.on_logits is not None:
+            for i in active_slots:
+                self.on_logits(self.slots[i], logits[i])
         total_ctx = int(self.lengths[active_slots].sum()) + len(active_slots)
         self.traces.record_decode(len(active_slots), total_ctx, t1 - t0)
         for i in active_slots:
@@ -243,7 +301,6 @@ class PagedEngine:
         Returns (logits at logit_pos, chunk ks, vs: (L, C, Hkv, hd))."""
         import math as _m
         from repro.kernels.flash_attention import flash_attention_ref
-        from repro.models.common import gated_mlp, rms_norm, rope
         a = self.arch
         hd = a.resolved_head_dim
         x = params["embed"][chunk_toks].astype(jnp.float32)[None]  # (1,C,D)
@@ -252,9 +309,12 @@ class PagedEngine:
         c = x.shape[1]
         positions = ctx_len + jnp.arange(c)
         ks_out, vs_out = [], []
+        norm = functools.partial(rms_norm, eps=a.norm_eps,
+                                 use_pallas=self.use_pallas,
+                                 interpret=self.cfg.interpret)
         for i in range(a.n_layers):
             p = jax.tree.map(lambda t: t[i], params["seg0"])
-            h = rms_norm(x, p["ln1"], a.norm_eps)
+            h = norm(x, p["ln1"])
             q = (h @ p["wq"]).reshape(1, c, a.n_heads, hd)
             k = (h @ p["wk"]).reshape(1, c, a.n_kv_heads, hd)
             v = (h @ p["wv"]).reshape(1, c, a.n_kv_heads, hd)
@@ -273,35 +333,37 @@ class PagedEngine:
             att = flash_attention_ref(q, k_all, v_all, causal=True,
                                       q_offset=ctx_len, kv_len=kv_len)
             x = x + att.reshape(1, c, -1) @ p["wo"]
-            h = rms_norm(x, p["ln2"], a.norm_eps)
+            h = norm(x, p["ln2"])
             x = x + gated_mlp(h, p["wg"], p["wu"], p["wd"], a.act)
-        x = rms_norm(x, params["final_ln"], a.norm_eps)
+        x = norm(x, params["final_ln"])
         head = params["embed"].T if a.tie_embeddings else params["head"]
         logits = x[0, logit_pos] @ head.astype(x.dtype)
         return logits, jnp.stack(ks_out), jnp.stack(vs_out)
 
     def _gather_ctx_kv(self, slot: int, ctx: int):
-        """Contiguous (L, ctx_pad, Hkv, hd) views of this slot's pages."""
+        """Contiguous (L, ctx_pad, Hkv, hd) copies of this slot's pages."""
         n_pages = (ctx + self.cfg.page_size - 1) // self.cfg.page_size
         n_pages = max(n_pages, 1)
         pages = self.block_tables[slot][:n_pages]
-        k = self.kv_k[:, pages].reshape(self.arch.n_layers,
-                                        n_pages * self.cfg.page_size,
-                                        self.arch.n_kv_heads, -1)
-        v = self.kv_v[:, pages].reshape(self.arch.n_layers,
-                                        n_pages * self.cfg.page_size,
-                                        self.arch.n_kv_heads, -1)
-        return k, v
+
+        def gather(pool):       # (L, n, Hkv, page, hd) -> (L, n*page, Hkv, hd)
+            return pool[:, pages].swapaxes(2, 3).reshape(
+                self.arch.n_layers, n_pages * self.cfg.page_size,
+                self.arch.n_kv_heads, -1)
+        return gather(self.kv_k), gather(self.kv_v)
 
     def _write_kv(self, slot: int, start: int, ks, vs) -> None:
+        """ks, vs: (L, n, Hkv, hd) for positions start .. start + n - 1."""
         n = ks.shape[1]
         pos = np.arange(start, start + n)
         pages = self.block_tables[slot][pos // self.cfg.page_size]
         offs = pos % self.cfg.page_size
-        self.kv_k = self.kv_k.at[:, pages, offs].set(
-            ks.astype(self.kv_k.dtype))
-        self.kv_v = self.kv_v.at[:, pages, offs].set(
-            vs.astype(self.kv_v.dtype))
+        # the two index arrays straddle a slice, so the indexed view is
+        # (n, L, Hkv, hd): token-major
+        self.kv_k = self.kv_k.at[:, pages, :, offs].set(
+            ks.swapaxes(0, 1).astype(self.kv_k.dtype))
+        self.kv_v = self.kv_v.at[:, pages, :, offs].set(
+            vs.swapaxes(0, 1).astype(self.kv_v.dtype))
 
     def _run_prefill(self, req: Request) -> None:
         s = req.l_in
@@ -318,7 +380,7 @@ class PagedEngine:
             done = 0
             while done < s:
                 n = min(cchunk, s - done)
-                bucket = max(8, 1 << (n - 1).bit_length())
+                bucket = prompt_bucket(n)
                 chunk = toks[done:done + n] + [0] * (bucket - n)
                 k_ctx, v_ctx = self._gather_ctx_kv(slot, max(done, 1))
                 # slice to exactly the valid context so chunk positions in
@@ -329,11 +391,13 @@ class PagedEngine:
                 self._write_kv(slot, done, ks[:, :n], vs[:, :n])
                 done += n
         else:
-            bucket = max(8, 1 << (s - 1).bit_length())  # pow-2 length buckets
+            bucket = prompt_bucket(s)
             padded = toks + [0] * (bucket - s)
             logits, ks, vs = self._prefill_jit(
                 self.params, jnp.asarray([padded]), s - 1)
             self._write_kv(slot, 0, ks[:, :s], vs[:, :s])
         self.lengths[slot] = s
+        if self.on_logits is not None:
+            self.on_logits(req, logits)
         req.tokens.append(int(np.asarray(jnp.argmax(logits, -1))))
         req.l_out = 1      # the prefill emits the first token (TTFT)

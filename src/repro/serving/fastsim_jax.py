@@ -76,7 +76,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.request import ReqState
 from repro.serving.fastsim import (DEFAULT_TAIL, check_colocated_envelope,
@@ -1518,7 +1517,7 @@ class _PooledSim:
 
         sig = None
         kern = None
-        with enable_x64():
+        with jax.enable_x64(True):
             while not self.done:
                 st, K = self.step_prepare()
                 cur = (self.W_cap, self.B, self.qcap)
@@ -1647,7 +1646,7 @@ def run_colocated_jax(scenario, seed: Optional[int] = None):
     rank_r, ttft_r, atgt_r, tagged = _tenant_arrays(ordered)
     # x64 is scoped, not a process-global flag: the serving models run in
     # jax's default 32-bit mode and must not see this engine's precision
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = _kernel_for(scenario, specs, trace, batched=False,
                          edf=multi, tagged=tagged)
         l_out, tds, t_first, t_fin, beats = (
@@ -1715,7 +1714,7 @@ def run_candidate_batch(scenarios) -> List:
     rank_r, ttft_r, atgt_r, tagged = _tenant_arrays(_ordered)
     padded = [base_spec] * W_max
     n_active = np.array([len(sl) for sl in spec_lists], dtype=np.int64)
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = _kernel_for(base, padded, trace, batched=True,
                          edf=multi, tagged=tagged)
         l_out, tds, t_first, t_fin, beats = (
@@ -1760,7 +1759,7 @@ def run_policy_candidate_batch(scenarios) -> List:
         for s in sims:
             s.run()
         return [_pooled_report(s, writeback=False) for s in sims]
-    with enable_x64():
+    with jax.enable_x64(True):
         while not all(s.done for s in sims):
             lens = []
             for s in sims:

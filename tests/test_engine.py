@@ -92,3 +92,26 @@ def test_engine_page_accounting():
     assert len(eng.free_pages) == free0, "pages leaked"
     assert eng.traces.decode_batches, "decode traces recorded"
     assert eng.traces.prefill_inputs, "prefill traces recorded"
+
+
+def test_warmup_leaves_engine_state_untouched():
+    arch, model, params, eng = _setup()
+    k0, v0 = np.asarray(eng.kv_k), np.asarray(eng.kv_v)
+    free0 = len(eng.free_pages)
+    assert eng.warmup([5, 12, 40]) > 0
+    np.testing.assert_array_equal(np.asarray(eng.kv_k), k0)
+    np.testing.assert_array_equal(np.asarray(eng.kv_v), v0)
+    assert len(eng.free_pages) == free0
+    assert not eng.traces.prefill_inputs and not eng.traces.decode_batches
+
+
+def test_kernel_choice_follows_device():
+    arch, _, params, eng = _setup()
+    assert eng.device == jax.devices()[0]
+    assert eng.use_pallas == (eng.device.platform == "tpu")
+    interp = PagedEngine(arch, params, EngineConfig(
+        max_batch=2, page_size=8, n_pages=16, max_pages_per_seq=4,
+        interpret=True))
+    assert interp.use_pallas        # interpret mode runs the Pallas kernels
+    assert eng.kv_k.shape == (arch.n_layers, 128, arch.n_kv_heads, 8,
+                              arch.resolved_head_dim)   # head-major pool

@@ -62,8 +62,8 @@ def test_flash_ref_chunk_invariance(kv_chunk):
 def test_paged_decode_sweep(b, hq, hkv, d, page, npages, maxp, dtype):
     rng = np.random.default_rng(2)
     q = jnp.asarray(rng.standard_normal((b, hq, d)), dtype)
-    kp = jnp.asarray(rng.standard_normal((npages, page, hkv, d)), dtype)
-    vp = jnp.asarray(rng.standard_normal((npages, page, hkv, d)), dtype)
+    kp = jnp.asarray(rng.standard_normal((npages, hkv, page, d)), dtype)
+    vp = jnp.asarray(rng.standard_normal((npages, hkv, page, d)), dtype)
     bt = jnp.asarray(rng.integers(0, npages, (b, maxp)), jnp.int32)
     lengths = jnp.asarray(rng.integers(1, maxp * page + 1, (b,)), jnp.int32)
     ref = paged_decode_ref(q, kp, vp, bt, lengths)

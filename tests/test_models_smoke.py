@@ -127,3 +127,21 @@ def test_flush_preserves_decode():
     l_b, _ = step(params, flushed, tok)
     np.testing.assert_allclose(np.asarray(l_a), np.asarray(l_b),
                                rtol=5e-2, atol=1e-1)
+
+
+@pytest.mark.parametrize("name", ASSIGNED_ARCHS)
+def test_init_matches_eager_draw(name):
+    """``LM.init`` draws each scaled-normal leaf straight into the param
+    dtype, with the values of the eager ``normal * scale`` for a seed."""
+    model = LM(reduced(get_arch(name)))
+    tmpl = model.param_template()
+    leaves = jax.tree.leaves(tmpl, is_leaf=lambda x: isinstance(x, tuple)
+                             and len(x) == 3 and isinstance(x[0], tuple))
+    keys = jax.random.split(jax.random.key(7), len(leaves))
+    got = jax.tree.leaves(model.init(jax.random.key(7)))
+    for k, (shape, _, scale), g in zip(keys, leaves, got):
+        if scale > 0:
+            want = (jax.random.normal(k, shape, jnp.float32)
+                    * scale).astype(model.dtype)
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(want, np.float32))

@@ -146,18 +146,18 @@ def test_sim002_flags_global_config_update():
 
 def test_sim002_flags_unscoped_enable_x64_call():
     diags = _check(X64Scope(), """
-        from jax.experimental import enable_x64
-        ctx = enable_x64()
+        import jax
+        ctx = jax.enable_x64(True)
         """, "src/repro/serving/foo.py")
     assert _codes(diags) == ["SIM002"]
 
 
 def test_sim002_allows_scoped_with_block():
     diags = _check(X64Scope(), """
-        from jax.experimental import enable_x64
+        import jax
 
         def run():
-            with enable_x64():
+            with jax.enable_x64(True):
                 return 1
         """, "src/repro/serving/foo.py")
     assert diags == []
