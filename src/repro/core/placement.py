@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -47,11 +47,15 @@ class WorkerState:
     next to V100 TP=8 — each built from its own Eq. 5-6 search)."""
 
     def __init__(self, wid: int, cfg: PlacementConfig, perf: PerfModel,
-                 slo: SLO):
+                 slo: SLO, refused: Optional[Dict[str, int]] = None):
         self.id = wid
         self.cfg = cfg
         self.perf = perf
         self.slo = slo
+        # refused placement checks by their first failing constraint; a
+        # cluster passes one table for all its workers
+        self.refused = refused if refused is not None \
+            else dict.fromkeys("bcde", 0)
         self.ongoing: List[Request] = []    # decoding (or placed) requests
         self.new_batch: List[Request] = []  # placed this heartbeat, not begun
         self.alive = True
@@ -227,10 +231,20 @@ class WorkerState:
     def feasible(self, reqs: Sequence[Request]) -> bool:
         if not self.alive or self.draining:
             return False
-        if self.cfg.split_phase:
-            return self._constraint_b(reqs) and self._constraint_e(reqs)
-        return (self._constraint_b(reqs) and self._constraint_c(reqs)
-                and self._constraint_d(reqs) and self._constraint_e(reqs))
+        if not self._constraint_b(reqs):
+            return self._refuse("b")
+        if not self.cfg.split_phase:
+            if not self._constraint_c(reqs):
+                return self._refuse("c")
+            if not self._constraint_d(reqs):
+                return self._refuse("d")
+        if not self._constraint_e(reqs):
+            return self._refuse("e")
+        return True
+
+    def _refuse(self, constraint: str) -> bool:
+        self.refused[constraint] += 1
+        return False
 
     # ---- mutation ------------------------------------------------------------
     def place(self, r: Request) -> None:

@@ -33,7 +33,8 @@ class Request:
     t_first_token: Optional[float] = None
     t_finish: Optional[float] = None
     t_decode_spent: float = 0.0            # decode wall time so far
-    t_prefill_start: Optional[float] = None
+    t_submit: Optional[float] = None       # ServingCluster.submit's time
+    t_prefill_start: Optional[float] = None  # its first prefill's start
     repredicted: bool = False              # Alg. 2: re-predicted after overrun
     tokens: Optional[object] = None        # actual token ids (engine only)
     # spot-preemption recovery: the worker serving this request was reclaimed

@@ -123,6 +123,7 @@ def main() -> None:
           f"finished | attainment {cluster.attainment():.2f} | "
           f"workers={len(cluster.workers)} | decode fit err="
           f"{cluster.perf.max_rel_err.get('decode', -1):.3f}")
+    print(f"[serve] {cluster.stats}")
 
 
 if __name__ == "__main__":

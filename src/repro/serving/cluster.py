@@ -35,9 +35,10 @@ from repro.core.placement import (PlacementConfig, WorkerState,
 from repro.core.rebalance import ErrorTracker, rebalance
 from repro.core.request import ReqState, Request
 from repro.core.scaling import Autoscaler, AutoscalerConfig
-from repro.core.slo import SLO
+from repro.core.slo import SLO, slo_attainment
 from repro.serving.engine import EngineConfig, PagedEngine
 from repro.serving.length_predictor import LengthPredictor
+from repro.serving.spans import ServeStats, span
 
 
 @dataclasses.dataclass
@@ -93,6 +94,7 @@ class ServingCluster:
         self.finished: List[Request] = []
         self.failed_events: List[int] = []
         self.session_home: Dict[int, int] = {}   # session -> last worker
+        self.stats = ServeStats()
         kv_cap = (engine_cfg.n_pages - 1) * engine_cfg.page_size \
             * arch.kv_bytes_per_token(dtype_bytes=4) / 2
         self.pcfg = PlacementConfig(gamma=cfg.gamma, theta=cfg.theta,
@@ -108,9 +110,11 @@ class ServingCluster:
         devices = jax.devices()
         eng = PagedEngine(self.arch, self.params, self.engine_cfg,
                           time_fn=self.time_fn,
-                          device=devices[self._wid % len(devices)])
+                          device=devices[self._wid % len(devices)],
+                          stats=self.stats)
         self._wid += 1
-        st = WorkerState(self._wid, self.pcfg, self.perf, self.slo)
+        st = WorkerState(self._wid, self.pcfg, self.perf, self.slo,
+                         refused=self.stats.refused)
         w = ClusterWorker(self._wid, eng, st)
         self.workers[self._wid] = w
         return w
@@ -159,8 +163,11 @@ class ServingCluster:
 
     # ---- request path ----------------------------------------------------------
     def submit(self, req: Request) -> None:
-        req.l_pred = self.predictor.predict(req.l_in)
-        self.queued.append(req)
+        with span("serve.submit", req=req.id):
+            req.t_submit = self.time_fn()
+            req.l_pred = self.predictor.predict(req.l_in)
+            self.queued.append(req)
+        self.stats.submitted += 1
 
     def _try_home(self, r: Request):
         """Sticky session affinity: the home worker takes the turn only if
@@ -194,6 +201,7 @@ class ServingCluster:
             if st is None:
                 still.append(r)
             else:
+                self.stats.placed += 1
                 r.state = ReqState.PLACED
                 if self.cfg.router == "sticky" and r.session_id >= 0:
                     self.session_home[r.session_id] = st.id
@@ -202,16 +210,38 @@ class ServingCluster:
     def heartbeat(self) -> List[Request]:
         """One control-plane cycle: place, re-balance, run engine iterations,
         refit models, straggler check. Returns newly finished requests."""
-        self._place_all()
+        self.stats.heartbeats += 1
+        with span("serve.heartbeat", beat=self.stats.heartbeats):
+            newly = self._beat()
+        self.finished.extend(newly)
+        return newly
+
+    def _refusals(self, before: Dict[str, int]) -> Dict[str, int]:
+        """Span stats: refusals by constraint since ``before``."""
+        return {f"refused_{c}": n - before[c]
+                for c, n in self.stats.refused.items()}
+
+    def _beat(self) -> List[Request]:
+        with span("serve.place") as sp:
+            before, placed = dict(self.stats.refused), self.stats.placed
+            self._place_all()
+            sp.set_metadata(placed=self.stats.placed - placed,
+                            left=len(self.queued), **self._refusals(before))
         if self.cfg.enable_rebalance and self.cfg.policy == "aladdin":
-            rebalance([w.state for w in self.workers.values()], self.tracker)
-            self.tracker.decay()
-        # hand placed requests to engines
-        for w in self.workers.values():
-            for r in list(w.state.new_batch):
-                w.engine.submit(r)
-                w.state.new_batch.remove(r)
-                w.state.ongoing.append(r)
+            with span("serve.rebalance") as sp:
+                before = dict(self.stats.refused)
+                rebalance([w.state for w in self.workers.values()],
+                          self.tracker)
+                self.tracker.decay()
+                sp.set_metadata(**self._refusals(before))
+        backlog = len(self.queued)
+        with span("serve.handoff", backlog=backlog):
+            for w in self.workers.values():
+                w.engine.backlog = backlog
+                for r in list(w.state.new_batch):
+                    w.engine.submit(r)
+                    w.state.new_batch.remove(r)
+                    w.state.ongoing.append(r)
         newly: List[Request] = []
         for w in list(self.workers.values()):
             for _ in range(self.cfg.heartbeat_iters):
@@ -223,22 +253,23 @@ class ServingCluster:
                     self.tracker.on_finish(r)
                     self.predictor.observe(r.l_in, r.l_real or r.l_out)
                     newly.append(r)
-            # re-prediction for underruns
-            for r in w.state.ongoing:
-                if r.l_out > r.l_pred and not r.repredicted:
-                    self.tracker.on_underrun(
-                        r, self.predictor.repredict(r.l_in, r.l_out))
-                    w.state.mark_dirty()
-            # refit perf models from live traces (workflow step 3)
-            self.perf.update_from_traces(w.engine.traces)
-        self._detect_stragglers()
-        # retire drained+empty workers
-        for wid, w in list(self.workers.items()):
-            if w.state.draining and not w.state.ongoing \
-                    and not w.engine.waiting \
-                    and len(self.workers) > self.cfg.min_workers:
-                del self.workers[wid]
-        self.finished.extend(newly)
+            with span("serve.refit", worker=w.id):
+                # re-prediction for underruns
+                for r in w.state.ongoing:
+                    if r.l_out > r.l_pred and not r.repredicted:
+                        self.tracker.on_underrun(
+                            r, self.predictor.repredict(r.l_in, r.l_out))
+                        w.state.mark_dirty()
+                # refit perf models from live traces (workflow step 3)
+                self.perf.update_from_traces(w.engine.traces)
+        with span("serve.upkeep"):
+            self._detect_stragglers()
+            # retire drained+empty workers
+            for wid, w in list(self.workers.items()):
+                if w.state.draining and not w.state.ongoing \
+                        and not w.engine.waiting \
+                        and len(self.workers) > self.cfg.min_workers:
+                    del self.workers[wid]
         return newly
 
     def run_until_drained(self, max_beats: int = 500) -> None:
@@ -274,12 +305,12 @@ class ServingCluster:
         for _, l_in, l_pred, l_real, arr in snap["queued"]:
             r = Request(l_in=l_in, l_pred=l_pred, l_real=l_real, arrival=arr)
             self.queued.append(r)
+            self.stats.submitted += 1
         while len(self.workers) < snap["n_workers"]:
             self._spawn_worker()
 
     # ---- metrics -----------------------------------------------------------------
     def attainment(self) -> float:
-        if not self.finished:
-            return 0.0
-        return sum(r.slo_ok(self.slo) for r in self.finished) \
-            / len(self.finished)
+        """Requests that met both SLOs over all submitted: one still
+        queued or in flight counts as a miss."""
+        return slo_attainment(self.finished, self.stats.submitted, self.slo)

@@ -32,6 +32,7 @@ from repro.core.request import ReqState, Request
 from repro.kernels.decode_attention import paged_decode_attention
 from repro.models.common import gated_mlp, rms_norm, rope, sinusoidal_pos
 from repro.models.model import LM, ExecConfig
+from repro.serving.spans import ServeStats, span
 
 
 @dataclasses.dataclass
@@ -132,7 +133,8 @@ class PagedEngine:
 
     def __init__(self, arch: ArchConfig, params, cfg: EngineConfig,
                  time_fn: Callable[[], float] = time.perf_counter,
-                 device: Optional[jax.Device] = None):
+                 device: Optional[jax.Device] = None,
+                 stats: Optional[ServeStats] = None):
         assert arch.family in (Family.DENSE, Family.AUDIO), \
             "engine path supports dense GQA archs (the paper's models)"
         self.arch = arch
@@ -153,6 +155,10 @@ class PagedEngine:
         self.free_pages = list(range(cfg.n_pages - 1, 0, -1))  # page 0 = null
         self.slots: List[Optional[Request]] = [None] * cfg.max_batch
         self.waiting: List[Request] = []
+        self.stats = stats if stats is not None else ServeStats()
+        # requests the cluster still queues after its placement pass; a
+        # decode step counts its empty slots while this is nonzero
+        self.backlog = 0
         kernels = dict(arch=arch, use_pallas=self.use_pallas,
                        interpret=cfg.interpret)
         self._prefill_jit = functools.partial(prefill_step, **kernels)
@@ -195,9 +201,6 @@ class PagedEngine:
     def running(self) -> List[Request]:
         return [r for r in self.slots if r is not None]
 
-    def kv_used_bytes(self) -> float:
-        return float(self.lengths.sum()) * self.kv_bytes_per_token / 2
-
     # ---- page management ----------------------------------------------------
     def _alloc_slot(self, req: Request, n_tokens: int) -> int:
         slot = self.slots.index(None)
@@ -235,63 +238,84 @@ class PagedEngine:
         """Run ONE iteration (a prefill batch or a decode batch). Returns the
         requests that finished."""
         finished: List[Request] = []
-        t0 = self.time_fn()
-        if self.waiting and self.can_admit(self.waiting[0].l_in + 8):
-            total_in, batch = 0, []
-            while self.waiting and self.can_admit(self.waiting[0].l_in + 8):
-                r = self.waiting.pop(0)
-                batch.append(r)
-                total_in += r.l_in
-                self._run_prefill(r)
-            t1 = self.time_fn()
-            self.traces.record_prefill(total_in, t1 - t0)
-            for r in batch:
-                r.t_first_token = now if now is not None else t1
-                r.state = ReqState.DECODING
-            return finished
-        active_slots = [i for i, r in enumerate(self.slots) if r is not None]
-        if not active_slots:
-            return finished
-        for i in list(active_slots):
-            if not self._ensure_page(i):
-                r = self.slots[i]          # out of pages: preempt youngest
-                self._free_slot(i)
-                r.l_out = 0
-                r.state = ReqState.QUEUED
-                self.waiting.insert(0, r)
-                active_slots.remove(i)
-        if not active_slots:
-            return finished
-        tokens = np.zeros((self.cfg.max_batch,), np.int64)
-        for i in active_slots:
-            tokens[i] = self.slots[i].tokens[-1]
-        active = np.zeros((self.cfg.max_batch,), bool)
-        active[active_slots] = True
-        logits, self.kv_k, self.kv_v = self._decode_jit(
-            self.params, self.kv_k, self.kv_v,
-            jnp.asarray(self.block_tables), jnp.asarray(self.lengths),
-            jnp.asarray(tokens), jnp.asarray(active))
-        nxt = np.asarray(jnp.argmax(logits, -1))
-        t1 = self.time_fn()
-        if self.on_logits is not None:
-            for i in active_slots:
-                self.on_logits(self.slots[i], logits[i])
-        total_ctx = int(self.lengths[active_slots].sum()) + len(active_slots)
-        self.traces.record_decode(len(active_slots), total_ctx, t1 - t0)
-        for i in active_slots:
-            r = self.slots[i]
-            self.lengths[i] += 1
-            r.l_out += 1
-            r.t_decode_spent += (t1 - t0)
-            r.tokens.append(int(nxt[i]))
-            self.traces.record_kv(
-                r.context, r.context * self.kv_bytes_per_token / 2)
-            if r.l_out >= min(r.l_real or self.cfg.max_new_tokens,
-                              self.cfg.max_new_tokens):
-                r.state = ReqState.FINISHED
-                r.t_finish = now if now is not None else t1
-                finished.append(r)
-                self._free_slot(i)
+        with span("serve.step"):
+            t0 = self.time_fn()
+            if self.waiting and self.can_admit(self.waiting[0].l_in + 8):
+                total_in, batch = 0, []
+                while self.waiting and \
+                        self.can_admit(self.waiting[0].l_in + 8):
+                    r = self.waiting.pop(0)
+                    batch.append(r)
+                    total_in += r.l_in
+                    self._run_prefill(r)
+                t1 = self.time_fn()
+                self.traces.record_prefill(total_in, t1 - t0)
+                for r in batch:
+                    r.t_first_token = now if now is not None else t1
+                    r.state = ReqState.DECODING
+                return finished
+            if all(r is None for r in self.slots):
+                return finished
+            b = self.cfg.max_batch
+            with span("serve.decode") as sp:
+                with span("serve.pages"):
+                    active_slots, preempted = [], 0
+                    for i, r in enumerate(self.slots):
+                        if r is None:
+                            continue
+                        if self._ensure_page(i):
+                            active_slots.append(i)
+                        else:                   # out of pages: preempt it
+                            self._free_slot(i)
+                            r.l_out = 0
+                            r.state = ReqState.QUEUED
+                            self.waiting.insert(0, r)
+                            preempted += 1
+                n = len(active_slots)
+                empty = b - n if n and self.backlog else 0
+                sp.set_metadata(active=n, slots=b, preempted=preempted,
+                                empty=empty)
+                self.stats.preemptions += preempted
+                if not n:
+                    return finished
+                with span("serve.launch"):
+                    tokens = np.zeros((b,), np.int64)
+                    for i in active_slots:
+                        tokens[i] = self.slots[i].tokens[-1]
+                    active = np.zeros((b,), bool)
+                    active[active_slots] = True
+                    logits, self.kv_k, self.kv_v = self._decode_jit(
+                        self.params, self.kv_k, self.kv_v,
+                        jnp.asarray(self.block_tables),
+                        jnp.asarray(self.lengths), jnp.asarray(tokens),
+                        jnp.asarray(active))
+                with span("serve.sample"):
+                    nxt = np.asarray(jnp.argmax(logits, -1))
+                t1 = self.time_fn()
+                with span("serve.bookkeep"):
+                    if self.on_logits is not None:
+                        for i in active_slots:
+                            self.on_logits(self.slots[i], logits[i])
+                    total_ctx = int(self.lengths[active_slots].sum()) + n
+                    self.traces.record_decode(n, total_ctx, t1 - t0)
+                    for i in active_slots:
+                        r = self.slots[i]
+                        self.lengths[i] += 1
+                        r.l_out += 1
+                        r.t_decode_spent += (t1 - t0)
+                        r.tokens.append(int(nxt[i]))
+                        self.traces.record_kv(
+                            r.context,
+                            r.context * self.kv_bytes_per_token / 2)
+                        if r.l_out >= min(r.l_real or self.cfg.max_new_tokens,
+                                          self.cfg.max_new_tokens):
+                            r.state = ReqState.FINISHED
+                            r.t_finish = now if now is not None else t1
+                            finished.append(r)
+                            self._free_slot(i)
+                self.stats.decode_steps += 1
+                self.stats.tokens_out += n
+                self.stats.empty_slot_steps += empty
         return finished
 
     def _chunk_fn(self, params, chunk_toks, k_ctx, v_ctx, ctx_len,
@@ -367,37 +391,55 @@ class PagedEngine:
 
     def _run_prefill(self, req: Request) -> None:
         s = req.l_in
-        slot = self._alloc_slot(req, s + 8)
-        toks = list(req.tokens[:s]) if req.tokens else \
-            list(np.random.default_rng(req.id).integers(
-                2, self.arch.vocab, s))
-        req.tokens = [int(t) for t in toks]
         cchunk = self.cfg.prefill_chunk
-        if cchunk and s > cchunk:
-            # Sarathi-style: process the prompt in fixed-size chunks, each
-            # attending to the already-written context pages
-            logits = None
-            done = 0
-            while done < s:
-                n = min(cchunk, s - done)
-                bucket = prompt_bucket(n)
-                chunk = toks[done:done + n] + [0] * (bucket - n)
-                k_ctx, v_ctx = self._gather_ctx_kv(slot, max(done, 1))
-                # slice to exactly the valid context so chunk positions in
-                # the concatenated KV line up with their logical positions
-                logits, ks, vs = self._chunk_jit(
-                    self.params, jnp.asarray(chunk), k_ctx[:, :done],
-                    v_ctx[:, :done], done, n - 1)
-                self._write_kv(slot, done, ks[:, :n], vs[:, :n])
-                done += n
-        else:
-            bucket = prompt_bucket(s)
-            padded = toks + [0] * (bucket - s)
-            logits, ks, vs = self._prefill_jit(
-                self.params, jnp.asarray([padded]), s - 1)
-            self._write_kv(slot, 0, ks[:, :s], vs[:, :s])
-        self.lengths[slot] = s
-        if self.on_logits is not None:
-            self.on_logits(req, logits)
-        req.tokens.append(int(np.asarray(jnp.argmax(logits, -1))))
+        chunked = bool(cchunk) and s > cchunk
+        if req.t_prefill_start is None:
+            req.t_prefill_start = self.time_fn()
+            if req.t_submit is not None:
+                self.stats.queue_wait_s += req.t_prefill_start - req.t_submit
+                self.stats.queue_waits += 1
+        with span("serve.prefill", req=req.id, tokens=s,
+                  bucket=prompt_bucket(cchunk if chunked else s)):
+            slot = self._alloc_slot(req, s + 8)
+            toks = list(req.tokens[:s]) if req.tokens else \
+                list(np.random.default_rng(req.id).integers(
+                    2, self.arch.vocab, s))
+            req.tokens = [int(t) for t in toks]
+            if chunked:
+                # Sarathi-style: process the prompt in fixed-size chunks,
+                # each attending to the already-written context pages
+                logits = None
+                done = 0
+                while done < s:
+                    n = min(cchunk, s - done)
+                    bucket = prompt_bucket(n)
+                    chunk = toks[done:done + n] + [0] * (bucket - n)
+                    with span("serve.prefill_program"):
+                        k_ctx, v_ctx = self._gather_ctx_kv(slot,
+                                                           max(done, 1))
+                        # slice to exactly the valid context so chunk
+                        # positions in the concatenated KV line up with
+                        # their logical positions
+                        logits, ks, vs = self._chunk_jit(
+                            self.params, jnp.asarray(chunk),
+                            k_ctx[:, :done], v_ctx[:, :done], done, n - 1)
+                    with span("serve.write_kv"):
+                        self._write_kv(slot, done, ks[:, :n], vs[:, :n])
+                    done += n
+            else:
+                bucket = prompt_bucket(s)
+                padded = toks + [0] * (bucket - s)
+                with span("serve.prefill_program"):
+                    logits, ks, vs = self._prefill_jit(
+                        self.params, jnp.asarray([padded]), s - 1)
+                with span("serve.write_kv"):
+                    self._write_kv(slot, 0, ks[:, :s], vs[:, :s])
+            self.lengths[slot] = s
+            with span("serve.first_token"):
+                if self.on_logits is not None:
+                    self.on_logits(req, logits)
+                req.tokens.append(int(np.asarray(jnp.argmax(logits, -1))))
         req.l_out = 1      # the prefill emits the first token (TTFT)
+        self.stats.prefills += 1
+        self.stats.prompt_tokens += s
+        self.stats.tokens_out += 1
