@@ -273,8 +273,12 @@ class PagedEngine:
                             preempted += 1
                 n = len(active_slots)
                 empty = b - n if n and self.backlog else 0
+                # the pages the decode kernel reads: each slot's context
+                # with the token this step writes
+                ps = self.cfg.page_size
+                pages = int(((self.lengths[active_slots] + ps) // ps).sum())
                 sp.set_metadata(active=n, slots=b, preempted=preempted,
-                                empty=empty)
+                                empty=empty, pages=pages)
                 self.stats.preemptions += preempted
                 if not n:
                     return finished
@@ -316,6 +320,7 @@ class PagedEngine:
                 self.stats.decode_steps += 1
                 self.stats.tokens_out += n
                 self.stats.empty_slot_steps += empty
+                self.stats.decode_kv_pages += pages
         return finished
 
     def _chunk_fn(self, params, chunk_toks, k_ctx, v_ctx, ctx_len,

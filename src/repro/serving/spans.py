@@ -38,7 +38,7 @@ NAMES = {
     "serve.write_kv": "the prompt's K/V scattered into its pages",
     "serve.first_token": "the argmax of the prefill logits and its sync",
     "serve.decode": "one decode iteration (active, slots, preempted,"
-                    " empty)",
+                    " empty, pages)",
     "serve.pages": "page checks and preemption",
     "serve.launch": "block-table, length and token uploads and the"
                     " decode_step dispatch",
@@ -70,5 +70,8 @@ class ServeStats:
     # slots empty in a decode step while the cluster queue held a request
     # after that heartbeat's placement
     empty_slot_steps: int = 0
+    # KV pages the decode steps' attention read: per active slot, the
+    # pages of its context and the token the step writes
+    decode_kv_pages: int = 0
     queue_wait_s: float = 0.0   # submit to first prefill, summed
     queue_waits: int = 0

@@ -59,16 +59,22 @@ def _footprint(compiled) -> int:
             + m.temp_size_in_bytes)
 
 
-@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "llama2-7b"])
+# block-table width where it is not the smoke's: the benchmark's nemo cell
+MAX_PAGES = {"mistral-nemo-12b": 528}
+
+
+@pytest.mark.parametrize("arch", ["phi4-mini-3.8b", "llama2-7b",
+                                  "mistral-nemo-12b"])
 def test_paged_decode_kernel_compiles(one_chip, arch):
     a = get_arch(arch)
     hd, e = a.resolved_head_dim, SMOKE
+    max_pages = MAX_PAGES.get(arch, e.max_pages_per_seq)
     pool = _sds(one_chip, (e.n_pages, a.n_kv_heads, e.page_size, hd),
                 jnp.float32)
     compiled = _compile(
         paged_decode_attention_pallas,
         _sds(one_chip, (e.max_batch, a.n_heads, hd), jnp.float32), pool, pool,
-        _sds(one_chip, (e.max_batch, e.max_pages_per_seq), jnp.int32),
+        _sds(one_chip, (e.max_batch, max_pages), jnp.int32),
         _sds(one_chip, (e.max_batch,), jnp.int32))
     assert compiled_kernels(compiled.as_text()) == {
         "paged_decode_attention": 1}
