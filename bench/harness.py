@@ -5,13 +5,17 @@ a chip count. Its parts live in files of their own, found by name:
 
   configuration   the ``file`` of its ``configs`` entry (sizes, engine,
                   cluster, SLO and the limits of ``correct``)
+  model family    ``bench/families/<family>.py``, named by the
+                  configuration's ``"family"``: the weight tree, the check
+                  of the program's architecture against the file, the
+                  float32 reference, and what the engine needs warmed
   traffic mix     ``bench/traffic/<traffic>.json``, read by ``traffic.py``
   per-layer metric ``bench/metrics/<metric name>.py``, a reader with
                   ``read(run) -> float | None``
   peaks           ``bench/peaks.json``, keyed by ``device_kind``
 
-So a later change adds a configuration, a mix, a cell or a metric by adding
-files and entries, with no edit to the code here.
+So a later change adds a configuration, a model family, a mix, a cell or a
+metric by adding files and entries, with no edit to the code here.
 """
 from __future__ import annotations
 
@@ -47,7 +51,11 @@ def load_cell(name: str, root: Path) -> Cell:
                        f"(have {sorted(cells)})")
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = json.loads((root / configs[w["config"]]["file"]).read_text())
+    file = configs[w["config"]]["file"]
+    config = json.loads((root / file).read_text())
+    if "family" not in config:
+        raise KeyError(f"configuration {file} names no \"family\" (the "
+                       "name of a file under bench/families/)")
     traffic = json.loads(
         (root / "bench" / "traffic" / f"{w['traffic']}.json").read_text())
     return Cell(name=name, config_name=w["config"],
@@ -62,12 +70,29 @@ def load_cell(name: str, root: Path) -> Cell:
 def metric_reader(name: str, root: Path
                   ) -> Callable[[object], Optional[float]]:
     """``read`` of ``<root>/bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
+    return _module(root / "bench" / "metrics" / f"{name}.py",
+                   "bench_metric_" + name).read
+
+
+def family(name: str, root: Path):
+    """The module ``<root>/bench/families/<name>.py``: ``check(cfg,
+    arch)``, ``shapes(cfg)``, ``logit_rows(params, tokens, rows, *, cfg,
+    mode, q_block)``, ``warm_up(engine, prompt_lengths)`` and
+    ``programs(engine, prompt_len)``."""
+    path = root / "bench" / "families" / f"{name}.py"
+    if not path.is_file():
+        have = sorted(p.stem for p in path.parent.glob("*.py"))
+        raise FileNotFoundError(f"no model family {name!r}: {path} is not "
+                                f"a file (have {have})")
+    return _module(path, "bench_family_" + name)
+
+
+def _module(path: Path, name: str):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
 def peaks(device_kind: str, root: Path) -> Dict[str, float]:

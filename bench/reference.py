@@ -1,54 +1,25 @@
-"""Plain float32 reference of the served models, and the check of ``correct``.
+"""What the check of ``correct`` shares across model families.
 
-The forward pass is written from the configuration file alone (it imports
-nothing of the program): token embedding (times ``embedding_multiplier``),
-then per layer RMSNorm, grouped-query attention with rotary embeddings
-(rotate-half, the first ``partial_rotary_factor`` of each head), a residual,
-RMSNorm and a SwiGLU MLP, then a final RMSNorm and the output head (the
-embedding, transposed, when tied). Every product runs at float32
-``HIGHEST`` precision on weights upcast from bfloat16, layer by layer under
-``lax.scan``, and attention in blocks of queries, so a whole prompt fits on
-one chip once the program is gone.
+A family's float32 reference (``bench/families/<name>.py``, ``logit_rows``)
+is written from the configuration file alone and imports nothing of the
+program. It builds on the helpers here: products at float32 ``HIGHEST``
+precision on weights upcast from bfloat16 (``_mm``), RMSNorm, rotate-half
+rotary embeddings, and causal grouped-query attention in blocks of queries.
 
-``mode="int8"`` swaps the float32 products of the linear layers and the
-head for the control's lower precision: activations rounded per row and
-weights per output column to 8-bit integers.
+``mode="int8"`` swaps the float32 products of ``_mm`` for the control's
+lower precision: activations rounded per row and weights per output column
+to 8-bit integers. ``served_readings`` runs a family's reference over the
+served sequences and reads the gaps that ``correct`` compares.
 """
 from __future__ import annotations
 
-import functools
-from typing import Dict, List, NamedTuple, Sequence
+from typing import Callable, Dict, List, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
-
-
-class Dims(NamedTuple):
-    d: int
-    layers: int
-    hq: int
-    hkv: int
-    hd: int
-    vocab: int
-    eps: float
-    theta: float
-    rot: int            # rotary dims per head
-    emb_mult: float
-    tied: bool
-
-
-def dims(cfg: dict) -> Dims:
-    hd = cfg["head_dim"]
-    rot = int(hd * cfg.get("partial_rotary_factor", 1.0))
-    return Dims(cfg["hidden_size"], cfg["num_hidden_layers"],
-                cfg["num_attention_heads"], cfg["num_key_value_heads"], hd,
-                cfg["vocab_size"], float(cfg["rms_norm_eps"]),
-                float(cfg["rope_theta"]), rot - rot % 2,
-                float(cfg.get("embedding_multiplier", 1.0)),
-                bool(cfg["tie_word_embeddings"]))
 
 
 def _int8(x, axis):
@@ -106,32 +77,6 @@ def _attention(q, k, v, q_block):
     return out.reshape(t, hq * hd)
 
 
-@functools.partial(jax.jit, static_argnames=("dm", "mode", "q_block"))
-def logit_rows(params, tokens, rows, *, dm: Dims, mode: str, q_block: int):
-    """Logits (len(rows), V) at positions ``rows`` of ``tokens`` (T,)."""
-    t = tokens.shape[0]
-    pos = jnp.arange(t)
-    x = params["embed"][tokens].astype(jnp.float32) * dm.emb_mult
-
-    def layer(x, p):
-        h = _norm(x, p["ln1"], dm.eps)
-        q = _mm(h, p["wq"], mode).reshape(t, dm.hq, dm.hd)
-        k = _mm(h, p["wk"], mode).reshape(t, dm.hkv, dm.hd)
-        v = _mm(h, p["wv"], mode).reshape(t, dm.hkv, dm.hd)
-        q = _rope(q, pos, dm.theta, dm.rot)
-        k = _rope(k, pos, dm.theta, dm.rot)
-        x = x + _mm(_attention(q, k, v, q_block), p["wo"], mode)
-        h = _norm(x, p["ln2"], dm.eps)
-        g = _mm(h, p["wg"], mode)
-        x = x + _mm(jax.nn.silu(g) * _mm(h, p["wu"], mode), p["wd"], mode)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, params["seg0"])
-    x = _norm(x[rows], params["final_ln"], dm.eps)
-    head = params["embed"].T if dm.tied else params["head"]
-    return _mm(x, head, mode)
-
-
 @jax.jit
 def gaps(ref_rows, ids):
     """How far each chosen token's reference logit lies below the best."""
@@ -151,13 +96,13 @@ class Served(NamedTuple):
     n_prompt: int
 
 
-def served_readings(params, cfg: dict, seqs: Sequence[Served],
-                    modes: Sequence[str] = ()) -> Dict[str, np.ndarray]:
+def served_readings(logit_rows: Callable, params, cfg: dict,
+                    seqs: Sequence[Served], modes: Sequence[str] = ()
+                    ) -> Dict[str, np.ndarray]:
     """Per served token: ``served`` is the gap of the token the program
     served; each of ``modes`` the gap of the token that mode's own logits
     put first, at the same positions. All gaps are read from the float32
-    reference."""
-    dm = dims(cfg)
+    reference, the family's ``logit_rows``."""
     ref = cfg["reference"]
     q_block, top = ref["q_block"], ref["max_tokens"]
     out: Dict[str, List[np.ndarray]] = {"served": []}
@@ -174,11 +119,11 @@ def served_readings(params, cfg: dict, seqs: Sequence[Served],
         rows[:n] = np.arange(s.n_prompt - 1, s.n_prompt - 1 + n)
         ids = np.zeros((m_pad,), np.int32)
         ids[:n] = served
-        f32 = logit_rows(params, toks, rows, dm=dm, mode="f32",
+        f32 = logit_rows(params, toks, rows, cfg=cfg, mode="f32",
                          q_block=q_block)
         out["served"].append(np.asarray(gaps(f32, ids))[:n])
         for m in modes:
-            low = logit_rows(params, toks, rows, dm=dm, mode=m,
+            low = logit_rows(params, toks, rows, cfg=cfg, mode=m,
                              q_block=q_block)
             first = jnp.argmax(low, axis=1).astype(jnp.int32)
             out[m].append(np.asarray(gaps(f32, first))[:n])

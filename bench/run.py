@@ -3,15 +3,16 @@
 
     python3 bench/run.py --workload phi4-conv --seed 7 --seconds 51 --trace 0
 
-The cell's configuration, traffic mix and per-layer metrics are found by
-name (``harness.py``). A run makes the weights from ``--seed`` on the
-device, builds a ``ServingCluster`` of one worker per chip, warms up every
-program the mix uses, fills a closed loop, and then drives ``submit`` and
-``heartbeat`` for ``--seconds``. Token times are stamped after every engine
-step. With ``--trace 1`` the window runs under the profiler and the
-result's metrics are the per-layer ones. After the window the program is
-freed and the served tokens of a sample of finished requests are checked
-against the float32 reference (``reference.py``).
+The cell's configuration, its model family, traffic mix and per-layer
+metrics are found by name (``harness.py``). A run makes the weights from
+``--seed`` on the device, builds a ``ServingCluster`` of one worker per
+chip, warms up every program the mix uses, fills a closed loop, and then
+drives ``submit`` and ``heartbeat`` for ``--seconds``. Token times are
+stamped after every engine step. With ``--trace 1`` the window runs under
+the profiler and the result's metrics are the per-layer ones. After the
+window the program is freed and the served tokens of a sample of finished
+requests are checked against the family's float32 reference
+(``reference.py``).
 
 The last stdout line is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` when traced). A run
@@ -48,23 +49,15 @@ NO_CHIP = 3
 
 
 # ---- the program under test -------------------------------------------------
-def program_arch(cfg: dict):
+def program_arch(cfg: dict, family):
     """The registered architecture the configuration runs, with its
-    overrides; refused where a size differs from the configuration file."""
+    overrides; the family refuses it where a size differs from the
+    configuration file."""
     from repro.configs import get_arch
     prog = cfg["program"]
     arch = dataclasses.replace(get_arch(prog["arch"]),
                                **prog.get("overrides", {}))
-    pairs = {"hidden_size": arch.d_model, "intermediate_size": arch.d_ff,
-             "num_attention_heads": arch.n_heads,
-             "num_key_value_heads": arch.n_kv_heads,
-             "head_dim": arch.resolved_head_dim,
-             "num_hidden_layers": arch.n_layers, "vocab_size": arch.vocab,
-             "rope_theta": arch.rope_theta, "rms_norm_eps": arch.norm_eps,
-             "tie_word_embeddings": arch.tie_embeddings}
-    bad = {k: (cfg[k], v) for k, v in pairs.items() if cfg[k] != v}
-    if bad:
-        raise ValueError(f"program arch differs from the file: {bad}")
+    family.check(cfg, arch)
     return arch
 
 
@@ -163,29 +156,11 @@ class Recorder:
 
 
 # ---- warm-up and fill -------------------------------------------------------
-def warm_up(cluster, lengths) -> None:
-    """Compile (or load) every program the mix uses, on every worker: the
-    prefill buckets and decode step (``PagedEngine.warmup``), the prompt
-    uploads and cache writes of every prompt length, and the argmax of
-    each step."""
-    import jax
-    import jax.numpy as jnp
-    from repro.serving.engine import prompt_bucket
+def warm_up(cluster, lengths, family) -> None:
+    """Compile (or load) every program the mix uses, on every worker, as
+    the configuration's family asks of its engine (``warm_up``)."""
     for w in cluster.workers.values():
-        eng = w.engine
-        eng.warmup(lengths)
-        a = eng.arch
-        slot = eng.slots.index(None)            # its block table is all 0:
-        for s in lengths:                       # writes land on null page 0
-            b = prompt_bucket(s)
-            jnp.asarray([[0] * b])              # the prompt's upload
-            ks = jnp.zeros((a.n_layers, b, a.n_kv_heads, a.resolved_head_dim),
-                           jnp.float32, device=eng.device)
-            eng._write_kv(slot, 0, ks[:, :s], ks[:, :s])
-        for shape in ((a.vocab,), (eng.cfg.max_batch, a.vocab)):
-            np.asarray(jnp.argmax(jnp.zeros(shape, jnp.float32,
-                                            device=eng.device), -1))
-        jax.block_until_ready(eng.kv_k)
+        family.warm_up(w.engine, lengths)
 
 
 def all_decoding(cluster) -> bool:
@@ -392,9 +367,9 @@ def main(argv=None, root: Path = ROOT, require_chip: bool = True,
          peaks: Optional[dict] = None, control: Optional[str] = None) -> int:
     """One run. Tests pass ``require_chip=False`` and their own ``peaks``
     to drive the rest of a run on the CPU. ``control.py`` passes a lower
-    precision of the reference (``reference.logit_rows``'s ``mode``): the
-    tokens it puts first then stand in for the served ones in the verdict,
-    which has to come out not correct."""
+    precision of the reference (the ``mode`` of the family's
+    ``logit_rows``): the tokens it puts first then stand in for the served
+    ones in the verdict, which has to come out not correct."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -425,13 +400,15 @@ def main(argv=None, root: Path = ROOT, require_chip: bool = True,
     used = devices[:cell.chips]
     annotate = jax.profiler.TraceAnnotation if args.trace else \
         _no_annotation
-    arch = program_arch(cfg)
-    params = jax.block_until_ready(weights.make(cfg, args.seed))
+    family = harness.family(cfg["family"], root)
+    arch = program_arch(cfg, family)
+    params = jax.block_until_ready(
+        weights.make(family.shapes(cfg), args.seed))
     traffic = Traffic(cell.traffic, arch.vocab, args.seed)
     cluster = build_cluster(cell, arch, params, traffic)
     ecfg = cluster.engine_cfg
     rec = Recorder(cluster, annotate)
-    warm_up(cluster, traffic.prompt_lengths())
+    warm_up(cluster, traffic.prompt_lengths(), family)
     print(f"[setup] {cell.name}: {arch.name} ({arch.n_layers} layers) on "
           f"{cell.chips} x {devices[0].device_kind}; engine max_batch "
           f"{ecfg.max_batch}, pages {ecfg.n_pages} x {ecfg.page_size}, "
@@ -459,7 +436,8 @@ def main(argv=None, root: Path = ROOT, require_chip: bool = True,
     n_failed = failures(w)
     mem = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
            for d in used]
-    kernels = pallas_kernels(cluster, traffic) if require_chip else {}
+    kernels = pallas_kernels(cluster, traffic, family) if require_chip \
+        else {}
     window_steps = [s for s in rec.steps
                     if s.t0 >= w["t_open"] and s.t0 < w["t_end"]]
     n_steps = {k: sum(s.kind == k for s in window_steps)
@@ -497,8 +475,9 @@ def main(argv=None, root: Path = ROOT, require_chip: bool = True,
     sample = check_sample(w, cfg["correct"]["sample_requests"], args.seed)
     del cluster, params, rec.stamps, w
     gc.collect()
-    readings = served_readings(weights.make(cfg, args.seed), cfg, sample,
-                               (control,) if control else ())
+    readings = served_readings(
+        family.logit_rows, weights.make(family.shapes(cfg), args.seed), cfg,
+        sample, (control,) if control else ())
     n_served = int(len(readings["served"]))
     if control:
         print(f"[control] {control} in the program's place; the program's "
@@ -542,24 +521,14 @@ def main(argv=None, root: Path = ROOT, require_chip: bool = True,
     return 0
 
 
-def pallas_kernels(cluster, traffic) -> Dict[str, Dict[str, int]]:
-    """Pallas kernels, by name, in the compiled programs the window ran."""
-    import jax.numpy as jnp
+def pallas_kernels(cluster, traffic, family) -> Dict[str, Dict[str, int]]:
+    """Pallas kernels, by name, in the compiled programs the window ran:
+    the family's ``programs`` of the first worker's engine."""
     from repro.kernels import compiled_kernels
-    from repro.serving.engine import decode_step, prefill_step, prompt_bucket
     eng = next(iter(cluster.workers.values())).engine
-    kw = dict(arch=eng.arch, use_pallas=eng.use_pallas,
-              interpret=eng.cfg.interpret)
-    s = prompt_bucket(max(traffic.prompt_lengths()))
-    pre = prefill_step.lower(eng.params, jnp.zeros((1, s), jnp.int32), s - 1,
-                             **kw)
-    b = eng.cfg.max_batch
-    dec = decode_step.lower(
-        eng.params, eng.kv_k, eng.kv_v, jnp.asarray(eng.block_tables),
-        jnp.asarray(eng.lengths), jnp.zeros((b,), jnp.int32),
-        jnp.zeros((b,), bool), page_size=eng.cfg.page_size, **kw)
+    lowered = family.programs(eng, max(traffic.prompt_lengths()))
     return {step: dict(compiled_kernels(lw.compile().as_text()))
-            for step, lw in (("prefill", pre), ("decode", dec))}
+            for step, lw in lowered.items()}
 
 
 _COUNTER: dict = {}

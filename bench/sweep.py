@@ -37,14 +37,16 @@ def main(argv=None) -> int:
         return run.NO_CHIP
     jax.config.update("jax_compilation_cache_dir",
                       str(run.ROOT / ".jax_cache"))
-    arch = run.program_arch(cell.config)
-    params = jax.block_until_ready(weights.make(cell.config, args.seed))
+    family = harness.family(cell.config["family"], run.ROOT)
+    arch = run.program_arch(cell.config, family)
+    params = jax.block_until_ready(
+        weights.make(family.shapes(cell.config), args.seed))
     for rate in [float(r) for r in args.rates.split(",")]:
         mix = dict(cell.traffic, rate_per_s=rate)
         traffic = Traffic(mix, arch.vocab, args.seed)
         cluster = run.build_cluster(cell, arch, params, traffic)
         rec = run.Recorder(cluster, run._no_annotation)
-        run.warm_up(cluster, traffic.prompt_lengths())
+        run.warm_up(cluster, traffic.prompt_lengths(), family)
         w = run.serve_window(cluster, traffic, rec, args.seconds,
                              run._no_annotation, fill_beats=0)
         e2e = run.end_to_end(w, rec)

@@ -1,5 +1,7 @@
-"""A benchmark root in a temporary directory, made of data files only:
-``BENCHMARK.json`` naming a tiny configuration, its mixes and a metric."""
+"""A benchmark root in a temporary directory, made of files only:
+``BENCHMARK.json`` naming two tiny configurations, their model families,
+their mixes and a metric. The second configuration's family,
+``dense_gqa_qkv_bias``, is a fixture that no file of ``bench/`` names."""
 from __future__ import annotations
 
 import json
@@ -15,19 +17,30 @@ def make_root(tmp: Path, metric: str = "idle_share.conv") -> Path:
     (tmp / "bench" / "configs").mkdir(parents=True)
     (tmp / "bench" / "traffic").mkdir()
     (tmp / "bench" / "metrics").mkdir()
-    shutil.copy(FIXTURES / "tiny.json", tmp / "bench/configs/tiny.json")
+    (tmp / "bench" / "families").mkdir()
+    for cfg in ("tiny", "tiny-qkv-bias"):
+        shutil.copy(FIXTURES / f"{cfg}.json",
+                    tmp / "bench" / "configs" / f"{cfg}.json")
+    shutil.copy(BENCH / "families" / "dense_gqa.py",
+                tmp / "bench/families/dense_gqa.py")
+    shutil.copy(FIXTURES / "dense_gqa_qkv_bias.py",
+                tmp / "bench/families/dense_gqa_qkv_bias.py")
     for mix in ("tiny-closed", "tiny-open"):
         shutil.copy(FIXTURES / f"{mix}.json",
                     tmp / "bench" / "traffic" / f"{mix}.json")
     shutil.copy(BENCH / "metrics" / f"{metric}.py",
                 tmp / f"bench/metrics/{metric}.py")
     bench = {
-        "configs": [{"name": "tiny", "file": "bench/configs/tiny.json"}],
+        "configs": [{"name": "tiny", "file": "bench/configs/tiny.json"},
+                    {"name": "tiny-qkv-bias",
+                     "file": "bench/configs/tiny-qkv-bias.json"}],
         "workloads": [
             {"name": "tiny-conv", "config": "tiny", "traffic": "tiny-closed",
              "chips": 1},
             {"name": "tiny-code", "config": "tiny", "traffic": "tiny-open",
-             "chips": 1}],
+             "chips": 1},
+            {"name": "tiny-qkv-conv", "config": "tiny-qkv-bias",
+             "traffic": "tiny-closed", "chips": 1}],
         "end_to_end": [
             {"name": "setup_s", "unit": "s"},
             {"name": "output_tok_s", "unit": "tokens/s"},

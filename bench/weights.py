@@ -1,12 +1,12 @@
 """Model weights from the seed, made on the device in one jitted call.
 
-The tree is the one the served path takes (``embed``, ``final_ln``, an
-untied ``head``, and the layers stacked under ``seg0``), in bfloat16, the
-type they are served in. Each matrix is normal with standard deviation
-1/sqrt(fan-in), the embedding 0.02, the norms ones. The reference makes the
-same tree again from the same seed once the program is gone, so it takes
-nothing from the program. A configuration may set the embedding's
-standard deviation (``init.embedding_std``, default 0.02).
+The leaves, their shapes and standard deviations come from the
+configuration's model family (``bench/families/<name>.py``, ``shapes``),
+in the order it gives them: leaf ``i`` takes the ``i``-th key split from
+the seed. Each is normal with its standard deviation, or ones where that is
+0 (a norm), in bfloat16, the type the served path takes. The reference
+makes the same tree again from the same seed once the program is gone, so
+it takes nothing from the program.
 """
 from __future__ import annotations
 
@@ -17,29 +17,6 @@ import jax
 import jax.numpy as jnp
 
 _DTYPE = jnp.bfloat16
-
-
-def shapes(cfg: dict) -> Dict[str, Tuple[Tuple[int, ...], float]]:
-    """Leaf name -> (shape, std); std 0 marks a norm (ones)."""
-    d, ff, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    n_l, hd = cfg["num_hidden_layers"], cfg["head_dim"]
-    qd, kvd = cfg["num_attention_heads"] * hd, cfg["num_key_value_heads"] * hd
-    out = {
-        "embed": ((v, d), cfg.get("init", {}).get("embedding_std", 0.02)),
-        "final_ln": ((d,), 0.0),
-        "seg0/ln1": ((n_l, d), 0.0),
-        "seg0/ln2": ((n_l, d), 0.0),
-        "seg0/wq": ((n_l, d, qd), d ** -0.5),
-        "seg0/wk": ((n_l, d, kvd), d ** -0.5),
-        "seg0/wv": ((n_l, d, kvd), d ** -0.5),
-        "seg0/wo": ((n_l, qd, d), qd ** -0.5),
-        "seg0/wg": ((n_l, d, ff), d ** -0.5),
-        "seg0/wu": ((n_l, d, ff), d ** -0.5),
-        "seg0/wd": ((n_l, ff, d), ff ** -0.5),
-    }
-    if not cfg["tie_word_embeddings"]:
-        out["head"] = ((d, v), d ** -0.5)
-    return out
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -61,9 +38,11 @@ def _make(spec: Tuple[Tuple[str, Tuple[int, ...], float], ...], key):
     return out
 
 
-def make(cfg: dict, seed: int) -> dict:
-    """The nested weight tree of configuration ``cfg`` for ``seed``."""
-    spec = tuple((n, s, float(std)) for n, (s, std) in shapes(cfg).items())
+def make(leaves: Dict[str, Tuple[Tuple[int, ...], float]], seed: int
+         ) -> dict:
+    """The nested weight tree of ``leaves`` (name -> (shape, std), a
+    family's ``shapes``) for ``seed``; a ``/`` in a name nests it."""
+    spec = tuple((n, s, float(std)) for n, (s, std) in leaves.items())
     flat = _make(spec, seed_key(seed))
     tree: dict = {}
     for name, leaf in flat.items():
