@@ -1,13 +1,13 @@
 """Config registry: importing this package registers all architectures."""
 from repro.configs.base import (                                    # noqa: F401
-    ALL_SHAPES, ArchConfig, Family, MoEConfig, PosEmb, SHAPES_BY_NAME,
-    SSMConfig, ShapeSpec, all_archs, get_arch, reduced, register,
-    shape_applicable, TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K,
+    ALL_SHAPES, ArchConfig, Family, MLAConfig, MoEConfig, PosEmb,
+    SHAPES_BY_NAME, SSMConfig, ShapeSpec, all_archs, get_arch, reduced,
+    register, shape_applicable, TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K,
 )
 
 # Assigned architecture pool (10) --------------------------------------------
 from repro.configs.mamba2_1p3b import MAMBA2_1P3B                   # noqa: F401
-from repro.configs.moonshot_v1_16b_a3b import MOONSHOT_V1_16B       # noqa: F401
+from repro.configs.moonlight_16b_a3b import MOONLIGHT_16B_A3B      # noqa: F401
 from repro.configs.qwen2_moe_a2p7b import QWEN2_MOE_A2P7B           # noqa: F401
 from repro.configs.musicgen_medium import MUSICGEN_MEDIUM           # noqa: F401
 from repro.configs.qwen2p5_32b import QWEN2P5_32B                   # noqa: F401
@@ -22,7 +22,7 @@ from repro.configs.llama2_paper import LLAMA2_7B, LLAMA2_13B, LLAMA2_70B  # noqa
 
 ASSIGNED_ARCHS = (
     "mamba2-1.3b",
-    "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "qwen2-moe-a2.7b",
     "musicgen-medium",
     "qwen2.5-32b",

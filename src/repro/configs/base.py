@@ -37,6 +37,33 @@ class MoEConfig:
     router_jitter: float = 0.0
     n_dense_layers: int = 0       # leading layers that stay dense (DeepSeek-style)
     d_shared: int = 0             # shared-expert hidden size (0 -> d_expert * n_shared)
+    d_dense: int = 0              # the leading dense layers' MLP width
+    # router: "softmax" (top-k of the softmax) or "sigmoid" (DeepSeek-V3's
+    # noaux_tc: top-k of sigmoid score + a correction bias that selects but
+    # does not weight, one group)
+    router: str = "softmax"
+    router_bias: bool = False     # the correction bias (sigmoid router)
+    norm_topk_prob: bool = True   # weights normalised over the chosen k
+    routed_scaling_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): one latent row of
+    ``kv_lora_rank + qk_rope_head_dim`` per token is the whole cache."""
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    q_lora_rank: Optional[int] = None    # None: q = h W_q, no low-rank q
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +94,7 @@ class ArchConfig:
     norm_eps: float = 1e-5
     act: str = "silu"             # silu -> SwiGLU; gelu -> GeGLU
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     # hybrid: attention block applied every `attn_every` layers (shared weights,
     # Zamba2-style); 0 = attention in every layer (dense), -1 = no attention (ssm)
@@ -125,7 +153,11 @@ class ArchConfig:
         return self.d_inner // self.ssm.head_dim
 
     # ---- parameter counting (used for roofline MODEL_FLOPS = 6ND) ----------
-    def param_count(self, active_only: bool = False) -> int:
+    def param_count(self, active_only: bool = False,
+                    held_experts: Optional[int] = None) -> int:
+        """Parameters of the whole model; ``held_experts`` counts that many
+        routed experts a layer, one chip's share of an expert-parallel
+        deployment (the router keeps every output)."""
         d, L = self.d_model, self.n_layers
         hd = self.resolved_head_dim
         n_params = 0
@@ -137,6 +169,13 @@ class ArchConfig:
         cross_set = set(self.cross_attn_layers)
         per_attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
             + (self.n_heads * hd) * d
+        if self.mla is not None:
+            m = self.mla
+            per_attn = d * self.n_heads * m.qk_head_dim \
+                + d * m.latent_dim + m.kv_lora_rank \
+                + m.kv_lora_rank * self.n_heads * (m.qk_nope_head_dim
+                                                   + m.v_head_dim) \
+                + self.n_heads * m.v_head_dim * d
         shared_attn_counted = False
         for i in range(L):
             n_params += 2 * d  # norms
@@ -158,22 +197,30 @@ class ArchConfig:
                 n_params += s.d_conv * (di + 2 * s.ngroups * s.d_state)  # conv1d
                 n_params += 2 * nh  # A_log, D
                 n_params += di * d  # out_proj
-            if self.d_ff > 0 and (self.moe is None or i < (self.moe.n_dense_layers or 0)
-                                  or self.family != Family.MOE):
+            moe_layer = self.moe is not None and self.family == Family.MOE
+            if moe_layer and i < self.moe.n_dense_layers and self.moe.d_dense:
+                n_params += 3 * d * self.moe.d_dense
+            elif self.d_ff > 0 and (not moe_layer
+                                    or i < self.moe.n_dense_layers):
                 n_params += 3 * d * self.d_ff  # SwiGLU: gate, up, down
-            elif self.moe is not None and self.family == Family.MOE \
-                    and i >= (self.moe.n_dense_layers or 0):
+            elif moe_layer and i >= self.moe.n_dense_layers:
                 m = self.moe
-                n_experts = m.top_k if active_only else m.n_experts
+                n_experts = m.top_k if active_only else (
+                    m.n_experts if held_experts is None else held_experts)
                 n_params += n_experts * 3 * d * m.d_expert
                 if m.n_shared_experts:
                     d_sh = m.d_shared or m.d_expert * m.n_shared_experts
                     n_params += 3 * d * d_sh
                 n_params += d * m.n_experts  # router
+                if m.router_bias:
+                    n_params += m.n_experts
         return n_params
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
-        """KV-cache bytes appended per generated/prefilled token (all layers)."""
+        """KV-cache bytes appended per generated/prefilled token (all layers):
+        K and V of every KV head, or with MLA the one latent row."""
+        if self.mla is not None:
+            return self.n_attn_layers * self.mla.latent_dim * dtype_bytes
         hd = self.resolved_head_dim
         n_attn = self.n_attn_layers + len(self.cross_attn_layers) * 0  # cross KV is fixed-size
         return n_attn * 2 * self.n_kv_heads * hd * dtype_bytes
@@ -239,8 +286,13 @@ def reduced(arch: ArchConfig, n_layers: int = 2, d_model: int = 64,
             top_k=min(arch.moe.top_k, 2), d_expert=d_ff,
             n_shared_experts=min(arch.moe.n_shared_experts, 1),
             d_shared=d_ff if arch.moe.n_shared_experts else 0,
-            n_dense_layers=min(arch.moe.n_dense_layers, 1))
+            n_dense_layers=min(arch.moe.n_dense_layers, 1),
+            d_dense=d_ff if arch.moe.d_dense else 0)
         kw["d_ff"] = 0 if arch.family == Family.MOE else d_ff
+    if arch.mla is not None:
+        kw["mla"] = dataclasses.replace(
+            arch.mla, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16)
     if arch.ssm is not None:
         kw["ssm"] = dataclasses.replace(arch.ssm, d_state=16, head_dim=16, chunk=32)
     if arch.attn_every:

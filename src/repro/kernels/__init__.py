@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the serving hot-spots, each with a pure-jnp oracle:
 
   flash_attention/   prefill & train attention (GQA, causal, VMEM-tiled)
-  decode_attention/  paged decode attention (block-table indirection) +
-                     flash-decoding partial/merge primitives
+  decode_attention/  paged decode attention (block-table indirection), its
+                     latent-row (MLA) form, and flash-decoding
+                     partial/merge primitives
   rmsnorm/           fused RMSNorm (+ residual add)
   ssd_scan/          Mamba-2 SSD chunked scan (state carried in VMEM)
 

@@ -10,6 +10,8 @@ Grid: (batch, q_heads, num_q_blocks, num_kv_blocks) — KV is the minormost
 dimension so the (m, l, acc) scratch carries across it, matching the
 multiple-visit accumulation pattern from the Pallas TPU docs. GQA is handled
 in the K/V index_maps (each q head reads its kv head; no HBM replication).
+V may have its own head dim (MLA's expanded prefill: q/k 192, v 128); the
+output and the accumulator take v's.
 """
 from __future__ import annotations
 
@@ -77,9 +79,11 @@ def flash_attention_pallas(q, k, v, *, scale: Optional[float] = None,
                            causal: bool = True, q_offset: int = 0,
                            block_q: int = 128, block_k: int = 128,
                            interpret: bool = False) -> jnp.ndarray:
-    """q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D). Returns (B, Sq, Hq, D)."""
+    """q, k: (B, Sq|Skv, Hq|Hkv, D); v: (B, Skv, Hkv, Dv). Returns
+    (B, Sq, Hq, Dv)."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
+    dv = v.shape[-1]
     assert hq % hkv == 0, (hq, hkv)
     group = hq // hkv
     scale = float(scale if scale is not None else d ** -0.5)
@@ -103,16 +107,16 @@ def flash_attention_pallas(q, k, v, *, scale: Optional[float] = None,
                          lambda bi, h, qi, ki: (bi, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda bi, h, qi, ki, g=group: (bi, h // g, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda bi, h, qi, ki, g=group: (bi, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
+        out_specs=pl.BlockSpec((1, 1, block_q, dv),
                                lambda bi, h, qi, ki: (bi, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
             pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_attention",

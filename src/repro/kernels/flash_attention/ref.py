@@ -33,7 +33,8 @@ def attention_dense_ref(q, k, v, *, causal: bool = True,
     """O(Sq*Skv)-memory reference. Ground truth for both the pallas kernel and
     the chunked implementation below.
 
-    q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D); Hq % Hkv == 0.
+    q: (B, Sq, Hq, D); k: (B, Skv, Hkv, D); v: (B, Skv, Hkv, Dv);
+    Hq % Hkv == 0.
     q_offset: global position of q[0] (for chunked prefill / decode).
     kv_len: optional (B,) valid KV lengths.
     """
@@ -94,9 +95,10 @@ def _flash_chunked(q, k, v, q_offset, kv_len, scale, *, causal, kv_chunk,
 
     m0 = jnp.full((b, hq, sq), NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((b, hq, sq), dtype=jnp.float32)
-    acc0 = jnp.zeros((b, hq, sq, d), dtype=jnp.float32)
+    dv = v.shape[-1]
+    acc0 = jnp.zeros((b, hq, sq, dv), dtype=jnp.float32)
     ks = k.reshape(b, n_chunks, kv_chunk, hkv, d).swapaxes(0, 1)
-    vs = v.reshape(b, n_chunks, kv_chunk, hkv, d).swapaxes(0, 1)
+    vs = v.reshape(b, n_chunks, kv_chunk, hkv, dv).swapaxes(0, 1)
     k0s = jnp.arange(n_chunks) * kv_chunk
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), (ks, vs, k0s))
     out = acc / jnp.maximum(l, 1e-37)[..., None]

@@ -37,6 +37,7 @@ from repro.models.attention import (AttnCache, cross_attention_decode,
                                     self_attention_decode,
                                     self_attention_full)
 from repro.models.common import gated_mlp, rms_norm, sinusoidal_pos
+from repro.models import mla
 from repro.models.mamba2 import (MambaCache, make_mamba_cache,
                                  mamba_block_decode, mamba_block_full)
 from repro.models.moe import moe_ffn, padded_experts, shared_expert_ffn
@@ -67,7 +68,25 @@ class SegmentSpec:
 Leaf = Tuple[Tuple[int, ...], Tuple[Optional[str], ...], float]
 
 
+def _mla_leaves(arch: ArchConfig) -> Dict[str, Leaf]:
+    """Latent attention (``models/mla.py``): q, the latent and rotary key,
+    the latent's norm, its expansion to per-head k_nope and v, output."""
+    d, h, m = arch.d_model, arch.n_heads, arch.mla
+    c = m.kv_lora_rank
+    return {
+        "wq": ((d, h * m.qk_head_dim), ("p_fsdp", "p_tp"), d ** -0.5),
+        "w_kv_a": ((d, m.latent_dim), ("p_fsdp", None), d ** -0.5),
+        "kv_ln": ((c,), (None,), -1.0),
+        "w_kv_b": ((c, h * (m.qk_nope_head_dim + m.v_head_dim)),
+                   (None, "p_tp"), c ** -0.5),
+        "wo": ((h * m.v_head_dim, d), ("p_tp", "p_fsdp"),
+               (h * m.v_head_dim) ** -0.5),
+    }
+
+
 def _attn_leaves(arch: ArchConfig, prefix: str = "") -> Dict[str, Leaf]:
+    if arch.mla is not None:
+        return _mla_leaves(arch)
     d = arch.d_model
     hd = arch.resolved_head_dim
     qd, kvd = arch.n_heads * hd, arch.n_kv_heads * hd
@@ -113,6 +132,8 @@ def _moe_layer_leaves(arch: ArchConfig, ep: int) -> Dict[str, Leaf]:
     s = 1.0 / math.sqrt(d)
     out.update({
         "router": ((d, m.n_experts), (None, None), s),
+        **({"router_bias": ((m.n_experts,), (None,), 0.0)}
+           if m.router_bias else {}),
         "w_gate": ((e_pad, d, m.d_expert), ("experts", "p_fsdp", None), s),
         "w_up": ((e_pad, d, m.d_expert), ("experts", "p_fsdp", None), s),
         "w_down": ((e_pad, m.d_expert, d), ("experts", None, "p_fsdp"),
@@ -243,7 +264,8 @@ class LM:
                     leaves = {"ln1": ((d,), (None,), -1.0),
                               "ln2": ((d,), (None,), -1.0)}
                     leaves.update(_attn_leaves(a))
-                    dff = a.moe.d_shared or a.moe.d_expert * 8
+                    dff = a.moe.d_dense or a.moe.d_shared \
+                        or a.moe.d_expert * 8
                     leaves.update(_mlp_leaves(a, dff))
                 else:
                     leaves = _dense_layer_leaves(a)
@@ -301,14 +323,39 @@ class LM:
         return rms_norm(x, w, self.arch.norm_eps, use_pallas=c.use_pallas,
                         interpret=c.interpret)
 
+    def _self_attention_full(self, h, p, positions, return_cache):
+        a, c = self.arch, self.cfg
+        if a.mla is None:
+            return self_attention_full(h, p, a, self.policy,
+                                       positions=positions,
+                                       kv_chunk=c.kv_chunk,
+                                       use_pallas=c.use_pallas,
+                                       interpret=c.interpret,
+                                       return_kv=return_cache)
+        out, row = mla.attention_full(h, p, a, positions, norm=self._norm,
+                                      use_pallas=c.use_pallas,
+                                      interpret=c.interpret,
+                                      kv_chunk=c.kv_chunk)
+        # the staged cache keeps the latent row as K and as V: one head
+        return (out, (row[:, :, None], row[:, :, None])) if return_cache \
+            else out
+
+    def _self_attention_decode(self, h, p, cache: AttnCache):
+        if self.arch.mla is None:
+            return self_attention_decode(h, cache, p, self.arch, self.policy)
+        return mla.self_attention_decode(h, cache, p, self.arch, self._norm)
+
+    def _kv_shape(self) -> Tuple[int, int]:
+        """(KV heads, width) of one cached token: the latent row for MLA."""
+        a = self.arch
+        if a.mla is not None:
+            return 1, a.mla.latent_dim
+        return a.n_kv_heads, a.resolved_head_dim
+
     def _dense_layer_full(self, x, p, positions, return_cache):
         a, pol = self.arch, self.policy
         h = self._norm(x, p["ln1"])
-        res = self_attention_full(h, p, a, pol, positions=positions,
-                                  kv_chunk=self.cfg.kv_chunk,
-                                  use_pallas=self.cfg.use_pallas,
-                                  interpret=self.cfg.interpret,
-                                  return_kv=return_cache)
+        res = self._self_attention_full(h, p, positions, return_cache)
         if return_cache:
             res, kv = res
         x = x + res
@@ -321,11 +368,7 @@ class LM:
     def _moe_layer_full(self, x, p, positions, return_cache):
         a, pol = self.arch, self.policy
         h = self._norm(x, p["ln1"])
-        res = self_attention_full(h, p, a, pol, positions=positions,
-                                  kv_chunk=self.cfg.kv_chunk,
-                                  use_pallas=self.cfg.use_pallas,
-                                  interpret=self.cfg.interpret,
-                                  return_kv=return_cache)
+        res = self._self_attention_full(h, p, positions, return_cache)
         if return_cache:
             res, kv = res
         x = x + res
@@ -339,7 +382,7 @@ class LM:
     def _moe_layer_decode(self, x, p, cache: AttnCache):
         a, pol = self.arch, self.policy
         h = self._norm(x, p["ln1"])
-        res, cache = self_attention_decode(h, cache, p, a, pol)
+        res, cache = self._self_attention_decode(h, p, cache)
         x = x + res
         h = self._norm(x, p["ln2"])
         out, _ = moe_ffn(h[:, None, :], p, a, pol, self.cfg.capacity_factor)
@@ -349,9 +392,9 @@ class LM:
         return x + out, cache
 
     def _dense_layer_decode(self, x, p, cache: AttnCache):
-        a, pol = self.arch, self.policy
+        a = self.arch
         h = self._norm(x, p["ln1"])
-        res, cache = self_attention_decode(h, cache, p, a, pol)
+        res, cache = self._self_attention_decode(h, p, cache)
         x = x + res
         h = self._norm(x, p["ln2"])
         x = x + gated_mlp(h, p["wg"], p["wu"], p["wd"], a.act)
@@ -579,12 +622,10 @@ class LM:
         return k, v
 
     def _attn_cache_from_kv(self, kv, b, s, s_max):
-        a = self.arch
         w = self.cfg.recent_window
         k, v = self._pad_kv(kv, s, s_max)
         lead = k.shape[:-4] if k.ndim > 4 else ()
-        hd = a.resolved_head_dim
-        zr = jnp.zeros(lead + (b, w, a.n_kv_heads, hd), self.dtype)
+        zr = jnp.zeros(lead + (b, w) + self._kv_shape(), self.dtype)
         return {"k_big": k, "v_big": v, "k_rec": zr, "v_rec": zr + 0,
                 "big_len": jnp.asarray(s, jnp.int32),
                 "rec_len": jnp.zeros((), jnp.int32)}
@@ -612,10 +653,11 @@ class LM:
         hd = a.resolved_head_dim
         w = self.cfg.recent_window
         dt = self.dtype
+        kv_shape = self._kv_shape()
 
         def attn_cache(*lead):
-            zb = jnp.zeros(lead + (batch, s_max, a.n_kv_heads, hd), dt)
-            zr = jnp.zeros(lead + (batch, w, a.n_kv_heads, hd), dt)
+            zb = jnp.zeros(lead + (batch, s_max) + kv_shape, dt)
+            zr = jnp.zeros(lead + (batch, w) + kv_shape, dt)
             return {"k_big": zb, "v_big": zb + 0, "k_rec": zr, "v_rec": zr + 0,
                     "big_len": jnp.zeros((), jnp.int32),
                     "rec_len": jnp.zeros((), jnp.int32)}
@@ -657,8 +699,8 @@ class LM:
 
         def attn_spec(*lead):
             nl = (None,) * len(lead)
-            big_shape = lead + (batch, s_max, a.n_kv_heads, hd)
-            rec_shape = lead + (batch, w, a.n_kv_heads, hd)
+            big_shape = lead + (batch, s_max) + self._kv_shape()
+            rec_shape = lead + (batch, w) + self._kv_shape()
             big = P_(nl + ("batch", "kv_seq", None, None), big_shape)
             rec = P_(nl + ("batch", None, None, None), rec_shape)
             return {"k_big": big, "v_big": big, "k_rec": rec, "v_rec": rec,

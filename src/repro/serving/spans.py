@@ -32,13 +32,14 @@ NAMES = {
                    " refit from its traces (worker)",
     "serve.upkeep": "straggler check and retirement of drained workers",
     "serve.step": "PagedEngine.step, one engine iteration",
-    "serve.prefill": "one request's prefill (req, tokens, bucket)",
+    "serve.prefill": "one request's prefill (req, tokens, bucket; with"
+                     " sparse experts held, held_max)",
     "serve.prefill_program": "the prompt's upload and the prefill"
                              " program's dispatch",
     "serve.write_kv": "the prompt's K/V scattered into its pages",
     "serve.first_token": "the argmax of the prefill logits and its sync",
     "serve.decode": "one decode iteration (active, slots, preempted,"
-                    " empty, pages)",
+                    " empty, pages; with sparse experts held, held_max)",
     "serve.pages": "page checks and preemption",
     "serve.launch": "block-table, length and token uploads and the"
                     " decode_step dispatch",
@@ -73,5 +74,11 @@ class ServeStats:
     # KV pages the decode steps' attention read: per active slot, the
     # pages of its context and the token the step writes
     decode_kv_pages: int = 0
+    # sparse experts (prefills and decode steps): top-k assignments routed
+    # to any expert; those to the experts this chip holds; and, per layer
+    # and step, the busiest held expert's tokens, summed
+    expert_assignments: int = 0
+    held_assignments: int = 0
+    held_expert_max: int = 0
     queue_wait_s: float = 0.0   # submit to first prefill, summed
     queue_waits: int = 0

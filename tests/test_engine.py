@@ -3,6 +3,7 @@ cache model path token-for-token, and page accounting must hold."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_arch, reduced
 from repro.core.request import ReqState, Request
@@ -115,3 +116,61 @@ def test_kernel_choice_follows_device():
     assert interp.use_pallas        # interpret mode runs the Pallas kernels
     assert eng.kv_k.shape == (arch.n_layers, 128, arch.n_kv_heads, 8,
                               arch.resolved_head_dim)   # head-major pool
+
+
+def _builds(fn) -> list:
+    """Names of the programs ``fn`` builds (lowers to MLIR)."""
+    built, on = [], [True]
+
+    def listen(event, duration, **kw):
+        if on[0] and \
+                event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            built.append(kw.get("fun_name", "?"))
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        fn()
+    finally:
+        on[0] = False
+    return built
+
+
+@pytest.mark.parametrize("name", ["granite-3-8b", "moonlight-16b-a3b"],
+                         ids=["dense", "latent-experts"])
+def test_warmup_leaves_nothing_to_build_while_serving(name):
+    """After ``warmup`` of the lengths served, prefill (its upload, its
+    cache write, its first token's argmax) and decode (its argmax) build
+    no program, with K/V pools and with a latent pool and held experts."""
+    arch = reduced(get_arch(name), n_layers=3, d_model=64, vocab=128)
+    eng = PagedEngine(arch, LM(arch).init(jax.random.key(0)), EngineConfig(
+        max_batch=2, page_size=8, n_pages=64, max_pages_per_seq=8))
+    lengths = [5, 12, 23]
+    eng.warmup(lengths)
+    rng = np.random.default_rng(4)
+    reqs = []
+    for n in lengths:
+        r = Request(l_in=n, l_pred=4, l_real=4)
+        r.tokens = [int(x) for x in rng.integers(2, arch.vocab, n)]
+        reqs.append(r)
+
+    def serve():
+        for r in reqs:
+            eng.submit(r)
+        while not all(r.state == ReqState.FINISHED for r in reqs):
+            eng.step()
+    assert _builds(serve) == []
+    assert list(eng.programs(23)) == ["prefill", "decode"]
+
+
+def test_programs_lower_prefill_and_decode():
+    """``programs`` lowers the prefill program of a length's bucket and
+    the decode step at the engine's shapes; the decode one runs."""
+    arch, model, params, eng = _setup()
+    lowered = eng.programs(12)
+    assert list(lowered) == ["prefill", "decode"]
+    assert all("stablehlo" in lw.as_text() for lw in lowered.values())
+    b = eng.cfg.max_batch
+    logits = lowered["decode"].compile()(
+        eng.params, eng.kv_k, eng.kv_v, jnp.asarray(eng.block_tables),
+        jnp.asarray(eng.lengths), jnp.zeros((b,), jnp.int32),
+        jnp.zeros((b,), bool))[0]
+    assert logits.shape == (b, arch.vocab)

@@ -17,11 +17,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
 from repro.kernels import compiled_kernels
-from repro.kernels.decode_attention import paged_decode_attention_pallas
+from repro.kernels.decode_attention import (mla_decode_attention_pallas,
+                                            paged_decode_attention_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.models.model import LM
-from repro.serving.engine import EngineConfig, decode_step, prefill_step
+from repro.serving.engine import (EngineConfig, decode_step,
+                                  mla_moe_decode_step, prefill_step)
 
 GIB = 1 << 30
 SMOKE = EngineConfig(max_batch=8, page_size=16, n_pages=512,
@@ -130,4 +132,42 @@ def test_engine_prefill_step_fits_one_chip(one_chip):
         use_pallas=True, interpret=False).compile()
     assert compiled_kernels(compiled.as_text()) == {
         "flash_attention": 1, "rmsnorm": 3}
+    assert _footprint(compiled) < 15 * GIB
+
+
+def test_mla_decode_kernel_compiles(one_chip):
+    """The latent paged-decode kernel at Moonlight's widths: 16 heads, a
+    576-wide row in a 640-wide pool, 32 sequences of 320 pages."""
+    compiled = _compile(
+        mla_decode_attention_pallas,
+        _sds(one_chip, (32, 16, 576), jnp.float32),
+        _sds(one_chip, (3014, 16, 640), jnp.float32),
+        _sds(one_chip, (32, 320), jnp.int32), _sds(one_chip, (32,), jnp.int32),
+        value_dim=512, scale=192 ** -0.5)
+    assert compiled_kernels(compiled.as_text()) == {"mla_decode_attention": 1}
+
+
+def test_moonlight_decode_step_fits_one_chip(one_chip):
+    """The latent engine's decode step with one chip's share of
+    Moonlight-16B-A3B (8 of 64 experts a layer, bf16 weights) and a
+    3,014-page float32 latent pool for 32 slots: the kernel once per
+    segment of layers (dense, experts), and the step's arguments, outputs
+    and temporaries inside the chip."""
+    arch = get_arch("moonlight-16b-a3b")
+    shapes = jax.eval_shape(LM(arch).init, jax.random.key(0))
+    experts = ("w_gate", "w_up", "w_down")
+    shapes["seg1"] = {k: jax.ShapeDtypeStruct(
+        (v.shape[0], 8) + v.shape[2:] if k in experts else v.shape, v.dtype)
+        for k, v in shapes["seg1"].items()}
+    params = jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), shapes)
+    b, pages = 32, 3014
+    pools = [_sds(one_chip, (n, pages, 16, 640), jnp.float32)
+             for n in (1, 26)]
+    compiled = mla_moe_decode_step.lower(
+        params, pools, _sds(one_chip, (b, 320), jnp.int32),
+        _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (b,), jnp.int32),
+        _sds(one_chip, (b,), jnp.bool_), arch=arch, page_size=16,
+        first_expert=0, use_pallas=True, interpret=False).compile()
+    kernels = compiled_kernels(compiled.as_text())
+    assert kernels["mla_decode_attention"] == 2
     assert _footprint(compiled) < 15 * GIB
