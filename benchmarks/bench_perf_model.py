@@ -60,7 +60,6 @@ def run(verbose: bool = True) -> List[Dict]:
                  "derived": f"max_rel_err={err_pre:.3f};k1={pm.k1:.2e}"})
 
     # --- Fig 7: decode time vs (batch, total context) -----------------------
-    import jax.numpy as jnp
     bs, cs, ts2 = [], [], []
     for b in (1, 2, 4, 8, 16):
         for ctx in (64, 256, 512):
@@ -76,12 +75,11 @@ def run(verbose: bool = True) -> List[Dict]:
             active = np.zeros((16,), bool)
             active[:b] = True
             tokens = np.full((16,), 3, np.int64)
-            args = (params, eng.kv_k, eng.kv_v, jnp.asarray(bt),
-                    jnp.asarray(lengths), jnp.asarray(tokens),
-                    jnp.asarray(active))
-            eng._decode_jit(*args)[0].block_until_ready()
+            # each step donates the pools and the engine keeps the new ones
+            eng.block_tables[:], eng.lengths[:] = bt, lengths
+            eng._launch(tokens, active)[0].block_until_ready()
             dt = _median_time(
-                lambda: eng._decode_jit(*args)[0].block_until_ready())
+                lambda: eng._launch(tokens, active)[0].block_until_ready())
             bs.append(b)
             cs.append(b * ctx)
             ts2.append(dt)

@@ -1,9 +1,11 @@
 """Pallas TPU paged decode-attention kernel (flash-decoding over a page pool).
 
-The KV cache lives in HBM as a global head-major page pool
-``(n_pages, Hkv, page, D)``, so one page of every KV head is one contiguous
-run (keep ``page`` a multiple of 8 for f32 pools, 16 for bf16: the TPU
-tiling). Each sequence owns a list of pages (block table).
+The KV cache lives in HBM as a global head-major page pool of every layer
+``(L, n_pages, Hkv, page, D)``, so one page of every KV head is one
+contiguous run (keep ``page`` a multiple of 8 for f32 pools, 16 for bf16:
+the TPU tiling). Each sequence owns a list of pages (block table). A call
+attends over one layer of the pool, its index a scalar-prefetched operand,
+so the caller hands over the whole pool and nothing slices a layer out.
 
 Grid: ``(batch,)``. A grid step walks its sequence's live pages only, in
 blocks of ``P`` pages (``pages_per_block``): the pages of a block are
@@ -46,7 +48,16 @@ def pages_per_block(hkv: int, page_size: int, head_dim: int, itemsize: int,
     return min(p, max_pages)
 
 
-def _decode_kernel(block_table_ref, lengths_ref,      # scalar-prefetch
+def whole_pool(*pools, layer, layer_ndim: int):
+    """Pools of every layer and the layer to read: a pool of
+    ``layer_ndim`` dims, one layer's, gains a leading layer axis of 1,
+    which moves no data, and is read at its layer 0."""
+    if pools[0].ndim == layer_ndim:
+        return (*(p[None] for p in pools), 0)
+    return (*pools, layer)
+
+
+def _decode_kernel(block_table_ref, lengths_ref, layer_ref,  # scalar-prefetch
                    q_ref, k_hbm, v_hbm, o_ref,
                    k_buf, v_buf, sems, slot_ref, pending_ref,
                    m_scr, l_scr, acc_scr, *,
@@ -63,13 +74,14 @@ def _decode_kernel(block_table_ref, lengths_ref,      # scalar-prefetch
         that hold a position below its length."""
         live = jnp.minimum(pages, pl.cdiv(lengths_ref[b], page_size)
                            - j * pages)
+        layer = layer_ref[0]
         for i in range(pages):
             # a dead entry past the table's end is read, never copied from
             page_id = block_table_ref[b, jnp.minimum(j * pages + i,
                                                      max_pages - 1)]
             dst = pl.ds(i * page_size, page_size)
             yield i < live, [
-                pltpu.make_async_copy(src.at[page_id],
+                pltpu.make_async_copy(src.at[layer, page_id],
                                       buf.at[slot, :, dst, :],
                                       sems.at[kv, slot])
                 for kv, (src, buf) in enumerate(((k_hbm, k_buf),
@@ -170,13 +182,18 @@ def _decode_kernel(block_table_ref, lengths_ref,      # scalar-prefetch
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, lengths,
-                                  *, scale: Optional[float] = None,
+                                  layer=0, *, scale: Optional[float] = None,
                                   interpret: bool = False) -> jnp.ndarray:
-    """q: (B, Hq, D); k/v_pages: (n_pages, Hkv, page, D);
-    block_table: (B, max_pages) int32; lengths: (B,) int32 -> (B, Hq, D).
-    ``interpret`` runs the kernel in the TPU interpreter (CPU tests)."""
+    """q: (B, Hq, D); k/v_pages: (L, n_pages, Hkv, page, D), or one
+    layer's (n_pages, Hkv, page, D); block_table: (B, max_pages) int32;
+    lengths: (B,) int32; layer: int32 scalar, the layer of the pool
+    attended over -> (B, Hq, D). ``interpret`` runs the kernel in the TPU
+    interpreter (CPU tests)."""
+    k_pages, v_pages, layer = whole_pool(k_pages, v_pages, layer=layer,
+                                         layer_ndim=4)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     b, hq, d = q.shape
-    n_pages, hkv, page_size, _ = k_pages.shape
+    _, n_pages, hkv, page_size, _ = k_pages.shape
     assert hq % hkv == 0
     group = hq // hkv
     max_pages = block_table.shape[1]
@@ -189,16 +206,16 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, lengths,
                                page_size=page_size, pages=pages)
     kv_block = (2, hkv, pages * page_size, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, hkv, group, d),
-                         lambda bi, bt, ln: (bi, 0, 0, 0)),
+                         lambda bi, bt, ln, ly: (bi, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, hkv, group, d),
-                               lambda bi, bt, ln: (bi, 0, 0, 0)),
+                               lambda bi, bt, ln, ly: (bi, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM(kv_block, k_pages.dtype),
             pltpu.VMEM(kv_block, v_pages.dtype),
@@ -219,5 +236,5 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, lengths,
             dimension_semantics=("arbitrary",)),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="paged_decode_attention",
-    )(block_table, lengths, qg, k_pages, v_pages)
+    )(block_table, lengths, layer, qg, k_pages, v_pages)
     return out.reshape(b, hq, d)

@@ -1,13 +1,14 @@
 """Pallas TPU paged decode attention over latent rows (MLA, absorbed form).
 
-The cache of a latent-attention layer is one pool of latent rows
-``(n_pages, page, W)``: per token, the normed latent ``c`` (its first
-``value_dim`` columns) and the rotary key ``k_pe`` after it, shared by
-every head, then zeros up to ``W``, a multiple of 128 lanes. A decode
+The cache of latent-attention layers is one pool of latent rows
+``(L, n_pages, page, W)``: per layer and token, the normed latent ``c``
+(its first ``value_dim`` columns) and the rotary key ``k_pe`` after it,
+shared by every head, then zeros up to ``W``, a multiple of 128 lanes. A decode
 query is absorbed into the same space, ``q = [q_nope W_UK^T, q_pe]`` per
 head, so every head scores against the whole row and accumulates over its
 first ``value_dim`` columns: one shared "KV head" for all heads, as in
-multi-query attention.
+multi-query attention. A call attends over one layer of the pool, its
+index a scalar-prefetched operand, so nothing slices a layer out.
 
 MLA's row is 576 wide. The TPU's (8, 128) tiling pads it to 640 in HBM
 whatever its logical width, and a page copy has to be tile-aligned, so the
@@ -31,7 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.decode_attention.decode_attention import (
-    KV_BLOCK_VMEM_BYTES, NEG_INF, _STAT_LANES)
+    KV_BLOCK_VMEM_BYTES, NEG_INF, _STAT_LANES, whole_pool)
 
 
 LANES = 128
@@ -54,7 +55,7 @@ def latent_pages_per_block(page_size: int, width: int, itemsize: int,
     return min(p, max_pages)
 
 
-def _mla_kernel(block_table_ref, lengths_ref,         # scalar-prefetch
+def _mla_kernel(block_table_ref, lengths_ref, layer_ref,  # scalar-prefetch
                 q_ref, pool_hbm, o_ref,
                 buf, sems, slot_ref, pending_ref,
                 m_scr, l_scr, acc_scr, *,
@@ -71,11 +72,12 @@ def _mla_kernel(block_table_ref, lengths_ref,         # scalar-prefetch
         below its length."""
         live = jnp.minimum(pages, pl.cdiv(lengths_ref[b], page_size)
                            - j * pages)
+        layer = layer_ref[0]
         for i in range(pages):
             page_id = block_table_ref[b, jnp.minimum(j * pages + i,
                                                      max_pages - 1)]
             yield i < live, pltpu.make_async_copy(
-                pool_hbm.at[page_id],
+                pool_hbm.at[layer, page_id],
                 buf.at[slot, pl.ds(i * page_size, page_size), :],
                 sems.at[slot])
 
@@ -170,14 +172,17 @@ def _mla_kernel(block_table_ref, lengths_ref,         # scalar-prefetch
 
 @functools.partial(jax.jit, static_argnames=("value_dim", "scale",
                                              "interpret"))
-def mla_decode_attention_pallas(q, pool, block_table, lengths, *,
+def mla_decode_attention_pallas(q, pool, block_table, lengths, layer=0, *,
                                 value_dim: int, scale: float,
                                 interpret: bool = False) -> jnp.ndarray:
-    """q: (B, H, C) absorbed queries; pool: (n_pages, page, W) latent rows,
-    W = ``pool_width(C)``; block_table: (B, max_pages) int32; lengths: (B,)
-    int32 -> (B, H, value_dim), attention over each row's first
-    ``value_dim`` columns."""
-    _, page_size, c = pool.shape
+    """q: (B, H, C) absorbed queries; pool: (L, n_pages, page, W) latent
+    rows, or one layer's (n_pages, page, W), W = ``pool_width(C)``;
+    block_table: (B, max_pages) int32; lengths: (B,) int32; layer: int32
+    scalar, the layer of the pool attended over -> (B, H, value_dim),
+    attention over each row's first ``value_dim`` columns."""
+    pool, layer = whole_pool(pool, layer=layer, layer_ndim=3)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    *_, page_size, c = pool.shape
     q = jnp.pad(q, ((0, 0), (0, 0), (0, c - q.shape[-1])))
     b, h, _ = q.shape
     max_pages = block_table.shape[1]
@@ -187,14 +192,14 @@ def mla_decode_attention_pallas(q, pool, block_table, lengths, *,
                                page_size=page_size, pages=pages,
                                value_dim=value_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, h, c), lambda bi, bt, ln: (bi, 0, 0)),
+            pl.BlockSpec((1, h, c), lambda bi, bt, ln, ly: (bi, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, h, value_dim),
-                               lambda bi, bt, ln: (bi, 0, 0)),
+                               lambda bi, bt, ln, ly: (bi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, pages * page_size, c), pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),          # one per slot
@@ -213,15 +218,16 @@ def mla_decode_attention_pallas(q, pool, block_table, lengths, *,
             dimension_semantics=("arbitrary",)),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="mla_decode_attention",
-    )(block_table, lengths, q, pool)
+    )(block_table, lengths, layer, q, pool)
 
 
-def mla_decode_ref(q, pool, block_table, lengths, *, value_dim: int,
-                   scale: float) -> jnp.ndarray:
-    """The jnp reference: every sequence's pages gathered into one
-    contiguous (B, S, C) block, masked by its length."""
+def mla_decode_ref(q, pool, block_table, lengths, layer=0, *,
+                   value_dim: int, scale: float) -> jnp.ndarray:
+    """The jnp reference: every sequence's pages of the layer gathered into
+    one contiguous (B, S, C) block, masked by its length."""
+    pool, layer = whole_pool(pool, layer=layer, layer_ndim=3)
     b, _, c = q.shape
-    rows = pool[block_table].reshape(b, -1, pool.shape[-1])[..., :c]
+    rows = pool[layer, block_table].reshape(b, -1, pool.shape[-1])[..., :c]
     rows = rows.astype(jnp.float32)
     s = jnp.einsum("bhc,bsc->bhs", q.astype(jnp.float32), rows,
                    preferred_element_type=jnp.float32) * scale
@@ -236,8 +242,9 @@ def mla_decode_ref(q, pool, block_table, lengths, *, value_dim: int,
     return out.astype(q.dtype)
 
 
-def mla_decode_attention(q, pool, block_table, lengths, *, value_dim: int,
-                         scale: float, use_pallas: Optional[bool] = None,
+def mla_decode_attention(q, pool, block_table, lengths, layer=0, *,
+                         value_dim: int, scale: float,
+                         use_pallas: Optional[bool] = None,
                          interpret: bool = False) -> jnp.ndarray:
     """The Pallas kernel on a TPU (or in interpret mode), else the jnp
     reference."""
@@ -245,7 +252,7 @@ def mla_decode_attention(q, pool, block_table, lengths, *, value_dim: int,
         use_pallas = jax.default_backend() == "tpu"
     if use_pallas:
         return mla_decode_attention_pallas(
-            q, pool, block_table, lengths, value_dim=value_dim, scale=scale,
-            interpret=interpret)
-    return mla_decode_ref(q, pool, block_table, lengths,
+            q, pool, block_table, lengths, layer, value_dim=value_dim,
+            scale=scale, interpret=interpret)
+    return mla_decode_ref(q, pool, block_table, lengths, layer,
                           value_dim=value_dim, scale=scale)

@@ -17,16 +17,21 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def paged_decode_attention(q, k_pages, v_pages, block_table, lengths, *,
-                           scale: Optional[float] = None,
+def paged_decode_attention(q, k_pages, v_pages, block_table, lengths,
+                           layer=0, *, scale: Optional[float] = None,
                            use_pallas: Optional[bool] = None,
                            interpret: bool = False) -> jnp.ndarray:
+    """Attention of ``q`` over layer ``layer`` of the page pools (L,
+    n_pages, Hkv, page, D), or over a one-layer pool (n_pages, Hkv, page,
+    D): the Pallas kernel on a TPU (or in interpret mode), else the jnp
+    reference."""
     use_pallas = _on_tpu() if use_pallas is None else use_pallas
     if use_pallas:
         return paged_decode_attention_pallas(
-            q, k_pages, v_pages, block_table, lengths, scale=scale,
+            q, k_pages, v_pages, block_table, lengths, layer, scale=scale,
             interpret=interpret)
-    return paged_decode_ref(q, k_pages, v_pages, block_table, lengths, scale)
+    return paged_decode_ref(q, k_pages, v_pages, block_table, lengths,
+                            layer, scale)
 
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_pallas",

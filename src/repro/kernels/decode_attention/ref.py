@@ -14,6 +14,8 @@ from typing import Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
+from repro.kernels.decode_attention.decode_attention import whole_pool
+
 NEG_INF = -1e30
 
 
@@ -83,21 +85,25 @@ def decode_attention_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
 
 def paged_decode_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                      v_pages: jnp.ndarray, block_table: jnp.ndarray,
-                     lengths: jnp.ndarray,
+                     lengths: jnp.ndarray, layer=0,
                      scale: Optional[float] = None) -> jnp.ndarray:
     """Paged decode attention.
 
     q:           (B, Hq, D)
-    k/v_pages:   (n_pages, Hkv, page_size, D)  — head-major page pool
+    k/v_pages:   (L, n_pages, Hkv, page_size, D) — head-major page pool of
+                 every layer, or one layer's (n_pages, Hkv, page_size, D)
     block_table: (B, max_pages) int32          — page ids per sequence
     lengths:     (B,) int32                    — valid tokens per sequence
+    layer:       int32 scalar                  — the layer attended over
     """
+    k_pages, v_pages, layer = whole_pool(k_pages, v_pages, layer=layer,
+                                         layer_ndim=4)
     b, hq, d = q.shape
-    _, hkv, page_size, _ = k_pages.shape
+    *_, hkv, page_size, _ = k_pages.shape
     max_pages = block_table.shape[1]
 
     def gather(pages):   # this batch's pages as contiguous (B, S, Hkv, D)
-        return pages[block_table].swapaxes(2, 3).reshape(
+        return pages[layer, block_table].swapaxes(2, 3).reshape(
             b, max_pages * page_size, hkv, d)
     return decode_attention_ref(q, gather(k_pages), gather(v_pages),
                                 lengths, scale)

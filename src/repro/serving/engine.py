@@ -16,9 +16,11 @@ V page pools, and latent-attention archs with sparse experts
 (Moonlight-16B-A3B), with one pool of latent rows: a dense segment of layers
 then a segment of expert layers, each a ``lax.scan``, the experts those of
 this worker's share (``EngineConfig.first_expert`` and the leading size of
-its expert weights). Execution is real JAX compute — iteration wall-times
-feed the TraceBuffer that fits the paper's performance models
-(Eqs. 1-3)."""
+its expert weights). The pools are donated to every program that writes
+them, the decode step and the prompt write, and written in place in their
+own layout; the kernels read a layer of the whole pool by its index.
+Execution is real JAX compute — iteration wall-times feed the TraceBuffer
+that fits the paper's performance models (Eqs. 1-3)."""
 from __future__ import annotations
 
 import dataclasses
@@ -86,14 +88,70 @@ def prefill_step(params, tokens, logit_pos, *, arch: ArchConfig,
             c0["v_big"][:, 0].astype(jnp.float32))
 
 
+def _write_rows(pool, layer, pages, offs, rows, active):
+    """Each active slot's row into layer ``layer`` of ``pool`` (L, n_pages,
+    ..., page, W) at its page and offset, in place; rows: (B, ..., W). A
+    slot's whole page of the layer is read, its row merged in, and the page
+    written back by ``dynamic_update_slice``: an update of whole pages
+    keeps the pool in its own layout, where one of a row each would have
+    the compiler lay the pool out anew for it, a copy of the pool a step.
+    A masked slot's page is written back as it was."""
+    size = (1, 1) + pool.shape[2:]
+    hit = jnp.arange(pool.shape[-2])[:, None] == offs[:, None, None]
+    for i in range(rows.shape[0]):
+        at = (layer, pages[i]) + (0,) * (pool.ndim - 2)
+        old = jax.lax.dynamic_slice(pool, at, size)
+        new = jnp.expand_dims(rows[i], -2).astype(pool.dtype)
+        pool = jax.lax.dynamic_update_slice(
+            pool, jnp.where(hit[i] & active[i], new, old), at)
+    return pool
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_pages(pools, rows, pages, off, n):
+    """Positions ``off`` .. ``off + n - 1`` of the run of pages ``pages``
+    take the first ``n`` of ``rows``, in every pool, in place. pools: (L,
+    n_pages, ..., page, W), donated; rows: each pool's (L, T, ..., W), n <=
+    T. Page by page, every layer of a page at once: the page is read,
+    merged with its rows of the run and written back by
+    ``dynamic_update_slice``, in the pool's own layout, so positions
+    outside the run keep what they held."""
+    page = pools[0].shape[-2]
+    # a run shorter than a page: pad it to one, so a page's slice fits
+    rows = [jnp.pad(r, [(0, 0), (0, max(page - r.shape[1], 0))]
+                    + [(0, 0)] * (r.ndim - 2)) for r in rows]
+    t = rows[0].shape[1]
+
+    def one_page(j, pools):
+        first = j * page - off              # the run's row at the page's 0
+        start = jnp.clip(first, 0, t - page)
+        pos = first + jnp.arange(page)
+        valid = ((pos >= 0) & (pos < n))[:, None]
+        out = []
+        for pool, r in zip(pools, rows):
+            # the page's rows, shifted back where the slice was clamped
+            new = jnp.roll(jax.lax.dynamic_slice_in_dim(r, start, page, 1),
+                           start - first, axis=1)
+            new = jnp.moveaxis(new, 1, -2)[:, None].astype(pool.dtype)
+            at = (0, pages[j]) + (0,) * (pool.ndim - 2)
+            old = jax.lax.dynamic_slice(pool, at, new.shape)
+            out.append(jax.lax.dynamic_update_slice(
+                pool, jnp.where(valid, new, old), at))
+        return out
+    return jax.lax.fori_loop(0, (off + n + page - 1) // page, one_page,
+                             list(pools))
+
+
 @functools.partial(jax.jit, static_argnames=("arch", "page_size",
-                                             "use_pallas", "interpret"))
+                                             "use_pallas", "interpret"),
+                   donate_argnums=(1, 2))
 def decode_step(params, kv_k, kv_v, block_tables, lengths, tokens, active, *,
                 arch: ArchConfig, page_size: int, use_pallas: bool,
                 interpret: bool):
     """One decode iteration for every slot (inactive ones masked).
-    kv_k/kv_v: (L, n_pages, Hkv, page, hd). Returns (logits, new kv_k,
-    new kv_v)."""
+    kv_k/kv_v: (L, n_pages, Hkv, page, hd), donated: each layer writes its
+    rows into them in place and its kernel reads its layer of them.
+    Returns (logits, kv_k, kv_v)."""
     a = arch
     hd = a.resolved_head_dim
     x = params["embed"][tokens].astype(jnp.float32)
@@ -104,12 +162,12 @@ def decode_step(params, kv_k, kv_v, block_tables, lengths, tokens, active, *,
     page_ids = jnp.take_along_axis(
         block_tables, (lengths // page_size)[:, None], axis=1)[:, 0]
     offs = lengths % page_size
-    msk = active[:, None, None]
     norm = functools.partial(rms_norm, eps=a.norm_eps, use_pallas=use_pallas,
                              interpret=interpret)
 
-    def layer(x, inp):
-        p, kk, vv = inp   # one layer's weights, (n_pages, Hkv, page, hd) pools
+    def layer(carry, inp):
+        x, kk, vv = carry       # the whole pools
+        p, li = inp             # one layer's weights and its index
         h = norm(x, p["ln1"])
         q = (h @ p["wq"]).reshape(-1, a.n_heads, hd)
         k = (h @ p["wk"]).reshape(-1, a.n_kv_heads, hd)
@@ -121,20 +179,18 @@ def decode_step(params, kv_k, kv_v, block_tables, lengths, tokens, active, *,
         if a.pos_emb == PosEmb.ROPE:
             q = rope(q[:, None], lengths[:, None], a.rope_theta)[:, 0]
             k = rope(k[:, None], lengths[:, None], a.rope_theta)[:, 0]
-        # (page_ids, :, offs) selects (B, Hkv, hd): one token slot per seq
-        kk = kk.at[page_ids, :, offs].set(
-            jnp.where(msk, k, kk[page_ids, :, offs]))
-        vv = vv.at[page_ids, :, offs].set(
-            jnp.where(msk, v, vv[page_ids, :, offs]))
+        kk = _write_rows(kk, li, page_ids, offs, k, active)
+        vv = _write_rows(vv, li, page_ids, offs, v, active)
         att = paged_decode_attention(q, kk, vv, block_tables, lengths + 1,
-                                     use_pallas=use_pallas,
+                                     li, use_pallas=use_pallas,
                                      interpret=interpret)
         x = x + att.reshape(x.shape[0], -1) @ p["wo"]
         h = norm(x, p["ln2"])
         x = x + gated_mlp(h, p["wg"], p["wu"], p["wd"], a.act)
-        return x, (kk, vv)
+        return (x, kk, vv), None
 
-    x, (kv_k, kv_v) = jax.lax.scan(layer, x, (params["seg0"], kv_k, kv_v))
+    (x, kv_k, kv_v), _ = jax.lax.scan(
+        layer, (x, kv_k, kv_v), (params["seg0"], jnp.arange(a.n_layers)))
     x = norm(x, params["final_ln"])
     head = params["embed"].T if a.tie_embeddings else params["head"]
     return x @ head.astype(x.dtype), kv_k, kv_v
@@ -150,15 +206,6 @@ PREFILL_EXPERT_TILE = 128
 # to ~0.7 where the dense models move by ~0.001. At float32 products the
 # served choices, and logits, are the float32 model's.
 MLA_MOE_PRECISION = "highest"
-
-
-@functools.partial(jax.jit, static_argnames=("n",), donate_argnums=0)
-def _scatter_latent(pool, rows, pages, offs, *, n: int):
-    """The first ``n`` of ``rows`` (L_seg, S, W) into ``pages``, ``offs``
-    (n,) of ``pool`` (L_seg, n_pages, page, W), in place: the pool is
-    donated, so a prompt's write needs no second pool."""
-    # adjacent index arrays: the indexed view is (L_seg, n, W)
-    return pool.at[:, pages, offs].set(rows[:, :n])
 
 
 def _pool_row(row):
@@ -218,15 +265,16 @@ def _mla_moe_prefill(params, tokens, logit_pos, arch, first_expert,
 
 @functools.partial(jax.jit, static_argnames=("arch", "page_size",
                                              "first_expert", "use_pallas",
-                                             "interpret"))
+                                             "interpret"),
+                   donate_argnums=1)
 def mla_moe_decode_step(params, kv_lat, block_tables, lengths, tokens, active,
                         *, arch: ArchConfig, page_size: int,
                         first_expert: int, use_pallas: bool, interpret: bool):
     """One decode iteration for every slot (inactive ones masked), absorbed
     latent attention over the latent pool. kv_lat: per segment (L_seg,
-    n_pages, page, W). Returns (logits, new kv_lat, held (expert layers,
-    held experts) int32: the active slots' assignments to each held
-    expert)."""
+    n_pages, page, W), donated and written in place. Returns (logits, new
+    kv_lat, held (expert layers, held experts) int32: the active slots'
+    assignments to each held expert)."""
     with jax.default_matmul_precision(MLA_MOE_PRECISION):
         return _mla_moe_decode(params, kv_lat, block_tables, lengths, tokens,
                                active, arch, page_size, first_expert,
@@ -243,34 +291,36 @@ def _mla_moe_decode(params, kv_lat, block_tables, lengths, tokens, active,
     norm = functools.partial(rms_norm, eps=a.norm_eps, use_pallas=use_pallas,
                              interpret=interpret)
 
-    def attention(x, p, pool):          # pool: (n_pages, page, W)
+    def attention(x, pool, p, li):      # pool: the segment's whole pool
         q_nope, q_pe, row = mla.project(norm(x, p["ln1"])[:, None], p, a,
                                         lengths[:, None], norm)
         q = mla.absorb(q_nope[:, 0], q_pe[:, 0], p, a)        # (B, H, C)
-        pool = pool.at[page_ids, offs].set(
-            jnp.where(active[:, None], _pool_row(row[:, 0]),
-                      pool[page_ids, offs]))
-        o = mla_decode_attention(q, pool, block_tables, lengths + 1,
+        pool = _write_rows(pool, li, page_ids, offs, _pool_row(row[:, 0]),
+                           active)
+        o = mla_decode_attention(q, pool, block_tables, lengths + 1, li,
                                  value_dim=m.kv_lora_rank,
                                  scale=mla.scale(a), use_pallas=use_pallas,
                                  interpret=interpret)
         return x + mla.absorbed_output(o, p, a), pool
 
-    def dense(x, inp):
-        x, pool = attention(x, *inp)
+    def dense(carry, inp):
+        x, pool = attention(*carry, *inp)
         h = norm(x, inp[0]["ln2"])
-        return x + gated_mlp(h, inp[0]["wg"], inp[0]["wu"], inp[0]["wd"],
-                             a.act), pool
+        return (x + gated_mlp(h, inp[0]["wg"], inp[0]["wu"], inp[0]["wd"],
+                              a.act), pool), None
 
-    def expert_layer(x, inp):
-        x, pool = attention(x, *inp)
+    def expert_layer(carry, inp):
+        x, pool = attention(*carry, *inp)
         out, held = held_experts_ffn(norm(x, inp[0]["ln2"]), inp[0], a,
                                      first=first_expert, valid=active)
-        return x + out, (pool, held)
+        return (x + out, pool), held
 
-    x, pool0 = jax.lax.scan(dense, x, (params["seg0"], kv_lat[0]))
-    x, (pool1, held) = jax.lax.scan(expert_layer, x,
-                                    (params["seg1"], kv_lat[1]))
+    def segment(body, x, params, pool):
+        return jax.lax.scan(body, (x, pool),
+                            (params, jnp.arange(pool.shape[0])))
+
+    (x, pool0), _ = segment(dense, x, params["seg0"], kv_lat[0])
+    (x, pool1), held = segment(expert_layer, x, params["seg1"], kv_lat[1])
     x = norm(x, params["final_ln"])
     return x @ params["head"].astype(x.dtype), [pool0, pool1], held
 
@@ -330,8 +380,14 @@ class PagedEngine:
         else:
             prefill, decode = prefill_step, decode_step
         self._prefill_jit = functools.partial(prefill, **kernels)
+        # the decode step jitted once more with its pools donated, so that
+        # a step wrapped in plain Python still runs as one program, named
+        # after the wrapper, and may hand a pool back as it came
+        kernels["page_size"] = cfg.page_size
         self._decode_jit = functools.partial(
-            decode, page_size=cfg.page_size, **kernels)
+            jax.jit(decode, static_argnames=tuple(kernels),
+                    donate_argnums=(1,) if self.mla else (1, 2)),
+            **kernels)
         self._chunk_jit = jax.jit(self._chunk_fn)
         # float32 pools: K and V of every KV head, or one latent row
         self.kv_bytes_per_token = arch.kv_bytes_per_token(dtype_bytes=4)
@@ -343,8 +399,8 @@ class PagedEngine:
     def warmup(self, prompt_lens: Iterable[int]) -> float:
         """Compile (or load), by running once, every program serving runs
         for prompts of these lengths: the prefill program of each length's
-        bucket and the decode step, each length's prompt upload and
-        cache-write scatter, and the argmax of either step's logits, so
+        bucket and the decode step, each length's prompt upload, each
+        bucket's cache write, and the argmax of either step's logits, so
         serving never waits on the compiler. Touches no request, page or
         trace: the writes put zeros on the null page 0. Returns seconds."""
         t0 = time.perf_counter()
@@ -613,36 +669,31 @@ class PagedEngine:
                 self.arch.n_kv_heads, -1)
         return gather(self.kv_k), gather(self.kv_v)
 
-    def _token_slots(self, table, start: int, n: int):
-        """(page, offset) of positions start .. start + n - 1 under a
-        block-table row."""
-        pos = np.arange(start, start + n)
-        return table[pos // self.cfg.page_size], pos % self.cfg.page_size
-
     def _write_kv(self, slot: int, start: int, ks, vs) -> None:
         """ks, vs: (L, n, Hkv, hd) for positions start .. start + n - 1."""
-        self._scatter_kv(self.block_tables[slot], start, ks, vs)
+        self._write(self.block_tables[slot], start, [ks, vs], ks.shape[1])
 
-    def _scatter_kv(self, table, start: int, ks, vs) -> None:
-        pages, offs = self._token_slots(table, start, ks.shape[1])
-        # the two index arrays straddle a slice, so the indexed view is
-        # (n, L, Hkv, hd): token-major
-        self.kv_k = self.kv_k.at[:, pages, :, offs].set(
-            ks.swapaxes(0, 1).astype(self.kv_k.dtype))
-        self.kv_v = self.kv_v.at[:, pages, :, offs].set(
-            vs.swapaxes(0, 1).astype(self.kv_v.dtype))
+    def _write(self, table, start: int, rows, n: int) -> None:
+        """The first ``n`` of ``rows``, each pool's (L, T, ..., W), into
+        positions start .. start + n - 1 under a block-table row: one
+        donated program for each T, whatever the start and n."""
+        ps = self.cfg.page_size
+        # the pages a run of T rows can touch, from any offset
+        pages = np.zeros(((rows[0].shape[1] + 2 * ps - 2) // ps,), np.int32)
+        got = table[start // ps:start // ps + len(pages)]
+        pages[:len(got)] = got
+        pools = _write_pages(jax.tree.leaves(self._pools()), rows, pages,
+                             np.int32(start % ps), np.int32(n))
+        if self.mla:
+            self.kv_lat = pools
+        else:
+            self.kv_k, self.kv_v = pools
 
     def _write_prompt(self, table, rows, n: int) -> None:
         """A prefill's cache of its first ``n`` positions into the pages of
         ``table``: K and V (L, S, Hkv, hd), or latent rows per segment
-        [(L_seg, S, W)]."""
-        if not self.mla:
-            ks, vs = rows
-            self._scatter_kv(table, 0, ks[:, :n], vs[:, :n])
-            return
-        pages, offs = self._token_slots(table, 0, n)
-        self.kv_lat = [_scatter_latent(pool, r, pages, offs, n=n)
-                       for pool, r in zip(self.kv_lat, rows[0])]
+        [(L_seg, S, W)]; one program for each bucket S."""
+        self._write(table, 0, list(rows[0] if self.mla else rows), n)
 
     def _run_prefill(self, req: Request) -> None:
         s = req.l_in
@@ -679,7 +730,8 @@ class PagedEngine:
                             self.params, jnp.asarray(chunk),
                             k_ctx[:, :done], v_ctx[:, :done], done, n - 1)
                     with span("serve.write_kv"):
-                        self._write_kv(slot, done, ks[:, :n], vs[:, :n])
+                        self._write(self.block_tables[slot], done, [ks, vs],
+                                    n)
                     done += n
             else:
                 bucket = prompt_bucket(s)

@@ -97,7 +97,8 @@ def test_engine_page_accounting():
 
 def test_warmup_leaves_engine_state_untouched():
     arch, model, params, eng = _setup()
-    k0, v0 = np.asarray(eng.kv_k), np.asarray(eng.kv_v)
+    # copies: a numpy view would keep the donated buffers alive on the CPU
+    k0, v0 = np.array(eng.kv_k), np.array(eng.kv_v)
     free0 = len(eng.free_pages)
     assert eng.warmup([5, 12, 40]) > 0
     np.testing.assert_array_equal(np.asarray(eng.kv_k), k0)
@@ -116,6 +117,92 @@ def test_kernel_choice_follows_device():
     assert interp.use_pallas        # interpret mode runs the Pallas kernels
     assert eng.kv_k.shape == (arch.n_layers, 128, arch.n_kv_heads, 8,
                               arch.resolved_head_dim)   # head-major pool
+
+
+def _small(name, max_batch=3):
+    """An engine of a three-layer arch at a tiny width, pages of 8."""
+    arch = reduced(get_arch(name), n_layers=3, d_model=64, vocab=128)
+    return PagedEngine(arch, LM(arch).init(jax.random.key(0)), EngineConfig(
+        max_batch=max_batch, page_size=8, n_pages=16, max_pages_per_seq=4))
+
+
+def _random_pools(eng, rng) -> list:
+    """Fills the engine's pools with noise, so that any write shows;
+    returns host copies of them (copies: a numpy view would keep the
+    buffers from being donated on the CPU)."""
+    pools = [rng.standard_normal(p.shape).astype(np.float32)
+             for p in jax.tree.leaves(eng._pools())]
+    if eng.mla:
+        eng.kv_lat = [jnp.asarray(p) for p in pools]
+    else:
+        eng.kv_k, eng.kv_v = (jnp.asarray(p) for p in pools)
+    return pools
+
+
+def _host(eng) -> list:
+    return [np.array(p) for p in jax.tree.leaves(eng._pools())]
+
+
+def _at(pool, pages, offs):
+    """The index of positions (pages, offs) of every layer: (L, n, ...,
+    W) for a latent pool, (n, L, Hkv, hd) for K or V."""
+    if pool.ndim == 5:
+        return np.s_[:, pages, :, offs]
+    return np.s_[:, pages, offs]
+
+
+@pytest.mark.parametrize("name", ["granite-3-8b", "moonlight-16b-a3b"],
+                         ids=["dense", "latent-experts"])
+def test_decode_step_writes_its_rows_in_place(name):
+    """A decode step writes each active slot's row of every layer and
+    nothing else: the masked slot's row, the rest of each written page and
+    every other page stay bit-identical. The pools it was given are
+    donated (deleted), and the engine holds the ones it returned."""
+    eng = _small(name)
+    old = _random_pools(eng, np.random.default_rng(5))
+    eng.block_tables[:] = [[3, 5, 0, 0], [7, 2, 0, 0], [9, 0, 0, 0]]
+    eng.lengths[:] = [11, 4, 0]              # rows (5, 3), (7, 4), (9, 0)
+    active = np.array([True, False, True])
+    given = jax.tree.leaves(eng._pools())
+    eng._launch(np.array([5, 6, 7]), active)
+    assert all(p.is_deleted() for p in given)
+    for o, new in zip(old, _host(eng)):
+        rows = _at(o, np.array([5, 9]), np.array([3, 0]))
+        assert (new[rows] != o[rows]).any()
+        new[rows] = o[rows]
+        np.testing.assert_array_equal(new, o)
+
+
+@pytest.mark.parametrize("start,n", [(5, 13), (3, 4)],
+                         ids=["across-pages", "inside-a-page"])
+@pytest.mark.parametrize("name", ["granite-3-8b", "moonlight-16b-a3b"],
+                         ids=["dense", "latent-experts"])
+def test_prompt_write_touches_only_its_rows(name, start, n):
+    """Rows written from a start off a page boundary (positions 5..17:
+    the end of page 4, all of page 9, the head of page 2; or 3..6 inside
+    page 4, fewer rows than a page) land where the block table says, and
+    every other row of every page and layer stays bit-identical. The
+    pools written are donated."""
+    eng = _small(name)
+    rng = np.random.default_rng(6)
+    old = _random_pools(eng, rng)
+    table = np.array([4, 9, 2, 0], np.int32)
+    rows = [rng.standard_normal(p.shape[:1] + (n,) + p.shape[2:-2]
+                                + p.shape[-1:]).astype(np.float32)
+            for p in old]
+    given = jax.tree.leaves(eng._pools())
+    if eng.mla:
+        eng._write(table, start, [jnp.asarray(r) for r in rows], n)
+    else:
+        eng.block_tables[1] = table
+        eng._write_kv(1, start, *map(jnp.asarray, rows))
+    assert all(p.is_deleted() for p in given)
+    pos = np.arange(start, start + n)
+    for o, r, new in zip(old, rows, _host(eng)):
+        want = o.copy()
+        want[_at(o, table[pos // 8], pos % 8)] = \
+            r.swapaxes(0, 1) if o.ndim == 5 else r
+        np.testing.assert_array_equal(new, want)
 
 
 def _builds(fn) -> list:

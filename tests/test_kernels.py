@@ -58,37 +58,52 @@ def test_flash_ref_chunk_invariance(kv_chunk):
 
 # A block is P pages (``pages_per_block``): 16 for f32 and 32 for bf16 at
 # the phi4 / nemo head shapes and page 16, 2 and 4 for the MHA case's page
-# of 32. ``lengths`` None draws them at random.
-@pytest.mark.parametrize("b,hq,hkv,d,page,npages,maxp,lengths", [
-    pytest.param(2, 8, 2, 64, 16, 32, 4, None, id="2-8-2-64-16-32-4"),
-    pytest.param(4, 4, 4, 32, 8, 16, 8, None, id="4-4-4-32-8-16-8"),
-    pytest.param(1, 16, 1, 128, 32, 8, 2, None, id="1-16-1-128-32-8-2"),
+# of 32. ``lengths`` None draws them at random. ``layers`` None hands the
+# kernel one layer's pool; (L, layer) a pool of L layers, every layer but
+# ``layer`` NaN, read at ``layer``.
+@pytest.mark.parametrize("b,hq,hkv,d,page,npages,maxp,lengths,layers", [
+    pytest.param(2, 8, 2, 64, 16, 32, 4, None, None, id="2-8-2-64-16-32-4"),
+    pytest.param(4, 4, 4, 32, 8, 16, 8, None, None, id="4-4-4-32-8-16-8"),
+    pytest.param(1, 16, 1, 128, 32, 8, 2, None, None,
+                 id="1-16-1-128-32-8-2"),
     # phi4-mini's heads; 40 pages, not a multiple of P; lengths of one
     # token, a page, an f32 block, a bf16 block, the whole table, between
     pytest.param(6, 24, 8, 128, 16, 64, 40, (1, 16, 256, 512, 640, 300),
-                 id="phi4-heads"),
+                 None, id="phi4-heads"),
     # mistral-nemo's heads; 33 pages; one past a page, short of a block
-    pytest.param(5, 32, 8, 128, 16, 64, 33, (1, 17, 528, 255, 257),
+    pytest.param(5, 32, 8, 128, 16, 64, 33, (1, 17, 528, 255, 257), None,
                  id="nemo-heads"),
     # MHA with a page of 32 tokens: a smaller P; lengths on a page, on an
     # f32 and a bf16 block, the whole table of 7 pages
-    pytest.param(5, 32, 32, 128, 32, 16, 7, (1, 32, 64, 128, 224),
+    pytest.param(5, 32, 32, 128, 32, 16, 7, (1, 32, 64, 128, 224), None,
                  id="mha-wide-page"),
+    # the whole pool of three layers, read at its first, a middle and its
+    # last layer
+    *[pytest.param(6, 24, 8, 128, 16, 64, 40, (1, 16, 256, 512, 640, 300),
+                   (3, layer), id=f"phi4-heads-layer{layer}-of-3")
+      for layer in (0, 1, 2)],
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_sweep(b, hq, hkv, d, page, npages, maxp, lengths,
-                            dtype):
+                            layers, dtype):
     rng = np.random.default_rng(2)
     q = jnp.asarray(rng.standard_normal((b, hq, d)), dtype)
-    kp = jnp.asarray(rng.standard_normal((npages, hkv, page, d)), dtype)
-    vp = jnp.asarray(rng.standard_normal((npages, hkv, page, d)), dtype)
+    kp = rng.standard_normal((npages, hkv, page, d))
+    vp = rng.standard_normal((npages, hkv, page, d))
+    layer = 0
+    if layers is not None:
+        n, layer = layers
+        kp, vp = (np.stack([p if i == layer else np.full_like(p, np.nan)
+                            for i in range(n)]) for p in (kp, vp))
+    kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
     bt = jnp.asarray(rng.integers(0, npages, (b, maxp)), jnp.int32)
     if lengths is None:
         lengths = rng.integers(1, maxp * page + 1, (b,))
     lengths = jnp.asarray(lengths, jnp.int32)
-    ref = paged_decode_ref(q, kp, vp, bt, lengths)
-    out = paged_decode_attention_pallas(q, kp, vp, bt, lengths,
+    ref = paged_decode_ref(q, kp, vp, bt, lengths, layer)
+    out = paged_decode_attention_pallas(q, kp, vp, bt, lengths, layer,
                                         interpret=True)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
 

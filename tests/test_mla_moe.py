@@ -85,16 +85,27 @@ def test_rope_pairs_rotate_interleaved_pairs():
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
-@pytest.mark.parametrize("lengths", [[0, 1, 16, 17], [45, 3, 32, 1]],
-                         ids=["empty-one-boundary", "ragged"])
-def test_mla_decode_kernel_matches_reference(lengths, monkeypatch):
+@pytest.mark.parametrize("lengths,layers", [
+    pytest.param([0, 1, 16, 17], None, id="empty-one-boundary"),
+    pytest.param([45, 3, 32, 1], None, id="ragged"),
+    # a pool of three layers, every layer but the one read NaN
+    *[pytest.param([45, 3, 32, 1], (3, layer), id=f"ragged-layer{layer}-of-3")
+      for layer in (0, 1, 2)],
+])
+def test_mla_decode_kernel_matches_reference(lengths, layers, monkeypatch):
     """Pages out of order, a length of 0 and of 1, a page boundary (16,
-    17); blocks of 2 pages, so a sequence spans several blocks."""
+    17); blocks of 2 pages, so a sequence spans several blocks; one
+    layer's pool, or the layer ``layers`` names of a whole pool."""
     b, h, c, value, page, n_pages, max_pages = 4, 4, 40, 32, 8, 32, 7
     width = pool_width(c)
     rng = np.random.default_rng(0)
-    pool = jnp.asarray(rng.standard_normal((n_pages, page, width)),
-                       jnp.float32)
+    pool = rng.standard_normal((n_pages, page, width))
+    layer = 0
+    if layers is not None:
+        n, layer = layers
+        pool = np.stack([pool if i == layer else np.full_like(pool, np.nan)
+                         for i in range(n)])
+    pool = jnp.asarray(pool, jnp.float32)
     q = jnp.asarray(rng.standard_normal((b, h, c)), jnp.float32)
     table = jnp.asarray(rng.permutation(np.arange(1, n_pages))[
         :b * max_pages].reshape(b, max_pages), jnp.int32)
@@ -105,8 +116,11 @@ def test_mla_decode_kernel_matches_reference(lengths, monkeypatch):
                         2 * 2 * page * width * 4)          # P = 2
     assert latent_pages_per_block(page, width, 4, max_pages) == 2
     got = mla_decode_attention_pallas.__wrapped__(    # not the jit cache
-        q, pool, table, ln, value_dim=value, scale=0.3, interpret=True)
-    want = mla_decode_ref(q, pool, table, ln, value_dim=value, scale=0.3)
+        q, pool, table, ln, layer, value_dim=value, scale=0.3,
+        interpret=True)
+    want = mla_decode_ref(q, pool, table, ln, layer, value_dim=value,
+                          scale=0.3)
+    assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, **F32_TOL)
     for i, n in enumerate(lengths):
         if n == 0:
